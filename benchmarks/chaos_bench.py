@@ -53,8 +53,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -543,47 +541,18 @@ def _bench_scenarios(scenario: str, **kw):
     return rows, summary
 
 
-def _spawn(args_list, json_path, n_devices=N_NODES * DEVS_PER_NODE):
-    """Re-exec the core in a subprocess with forced host devices so the
-    recovery reshards are real multi-device collectives."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("PYTHONPATH", "")
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(here, "src"), here, env["PYTHONPATH"]])
-    cmd = [sys.executable, "-m", "benchmarks.chaos_bench", "--core"]
-    cmd += list(args_list)
-    if json_path:
-        cmd += ["--json", json_path]
-    r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                       timeout=600, cwd=here)
-    if r.returncode != 0:
-        return None
-    rows = []
-    for line in r.stdout.splitlines():
-        parts = line.strip().split(",")
-        if len(parts) == 3 and parts[0].startswith("chaos/"):
-            rows.append((parts[0], float(parts[1]), parts[2]))
-    return rows or None
-
-
 def run(smoke: bool = False, json_path: str | None = None,
         scenario: str = "all"):
-    """Entry point for ``benchmarks.run --only chaos``."""
-    args_list = ["--scenario", scenario] + (["--smoke"] if smoke else [])
-    rows = _spawn(args_list, json_path)
-    if rows is not None:
-        return rows
-    # fallback: in-process (degraded: single-device reshards are aliases)
-    rows, summary = _bench_scenarios(
-        scenario,
-        **({"steps": 4, "dim": 256, "sleep_s": 0.005} if smoke else {}))
+    """Entry point for ``benchmarks.run --only chaos``: the core runs in a
+    CPU child with forced host devices so the recovery reshards are real
+    multi-device collectives; a failed child fails the bench."""
+    from benchmarks.common import spawn_host_devices
+    args_list = (["--core", "--scenario", scenario]
+                 + (["--smoke"] if smoke else []))
     if json_path:
-        with open(json_path, "w") as f:
-            json.dump(summary, f, indent=2)
-    return rows
+        args_list += ["--json", json_path]
+    return spawn_host_devices("benchmarks.chaos_bench", args_list, "chaos/",
+                              N_NODES * DEVS_PER_NODE)
 
 
 def main():
@@ -600,11 +569,11 @@ def main():
                     help="write the summary dict to this path")
     args = ap.parse_args()
 
-    from benchmarks.common import emit
+    from benchmarks.common import emit, platform_rows
     kw = {"steps": 4, "dim": 256, "sleep_s": 0.005} if args.smoke else {}
     if args.core:
         rows, summary = _bench_scenarios(args.scenario, **kw)
-        emit(rows)
+        emit(platform_rows(rows))
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(summary, f, indent=2)

@@ -181,3 +181,44 @@ def emit(rows):
         print(f"{name},{us:.1f},{derived}")
     sys.stdout.flush()
     return rows
+
+
+def platform_rows(rows):
+    """Append ``platform=<jax backend>`` to each row's derived column: the
+    platform this process measured the row on."""
+    import jax
+    tag = f"platform={jax.default_backend()}"
+    return [(name, us, f"{derived};{tag}" if derived else tag)
+            for name, us, derived in rows]
+
+
+def spawn_host_devices(module: str, args, prefix: str, n_devices: int = 4):
+    """Run ``python -m <module> <args>`` in a child pinned to the CPU with
+    ``n_devices`` forced host devices, so a multi-device path is rehearsed
+    with real collectives on one host.  The child never asks for an
+    accelerator: a parent that has touched JAX already holds it.
+
+    Returns the child's ``prefix*`` CSV rows (tagged ``platform=cpu`` by
+    the child).  A child that fails or prints no rows raises."""
+    import os
+    import subprocess
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(here, "src"), here, env.get("PYTHONPATH", "")])
+    cmd = [sys.executable, "-m", module, *args]
+    r = subprocess.run(cmd, capture_output=True, text=True, env=env,
+                       timeout=600, cwd=here)
+    rows = []
+    for line in r.stdout.splitlines():
+        parts = line.strip().split(",")
+        if len(parts) == 3 and parts[0].startswith(prefix):
+            rows.append((parts[0], float(parts[1]), parts[2]))
+    if r.returncode != 0 or not rows:
+        raise RuntimeError(
+            f"{' '.join(cmd[1:])} exited {r.returncode} with {len(rows)} "
+            f"{prefix} rows; stderr tail:\n{r.stderr[-4000:]}")
+    return rows

@@ -17,9 +17,9 @@ the actor's leaves change layout between gen and train).  A depth-1 parity
 check asserts the pipelined scheduler reproduces the sequential engine's
 data pools bit-for-bit.
 
-The core runs in a subprocess with 4 forced host devices so the reshard is
-a genuine multi-device collective; falls back to in-process execution
-(degraded: single-device reshards are pure aliases) if spawning fails.
+The core runs in a CPU subprocess with 4 forced host devices so the
+reshard is a genuine multi-device collective; its rows say
+``platform=cpu``, and a failed subprocess fails the bench.
 
 Wired into ``benchmarks/run.py`` as ``--only pipeline``; CI runs
 ``--smoke --json`` and uploads the artifact.
@@ -29,9 +29,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
 
@@ -228,45 +225,16 @@ def bench_pipeline(steps=8, sleep_s=0.05, pipeline_depth=2):
     return rows, summary
 
 
-def _spawn(args_list, json_path, n_devices=4):
-    """Re-exec the core in a subprocess with forced host devices so the
-    reshard is a real multi-device collective."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("PYTHONPATH", "")
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(here, "src"), here, env["PYTHONPATH"]])
-    cmd = [sys.executable, "-m", "benchmarks.pipeline_bench", "--core"]
-    cmd += args_list
-    if json_path:
-        cmd += ["--json", json_path]
-    r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                       timeout=600, cwd=here)
-    if r.returncode != 0:
-        return None
-    rows = []
-    for line in r.stdout.splitlines():
-        parts = line.strip().split(",")
-        if len(parts) == 3 and parts[0].startswith("pipeline/"):
-            rows.append((parts[0], float(parts[1]), parts[2]))
-    return rows or None
-
-
 def run(smoke: bool = False, json_path: str | None = None):
-    """Entry point for ``benchmarks.run --only pipeline``."""
-    args_list = ["--smoke"] if smoke else []
-    rows = _spawn(args_list, json_path)
-    if rows is not None:
-        return rows
-    # fallback: in-process (degraded single-device reshards)
-    rows, summary = bench_pipeline(
-        **({"steps": 5, "sleep_s": 0.03} if smoke else {}))
+    """Entry point for ``benchmarks.run --only pipeline``: the core runs in
+    a CPU child with 4 forced host devices so the reshard is a real
+    multi-device collective; a failed child fails the bench."""
+    from benchmarks.common import spawn_host_devices
+    args_list = ["--core"] + (["--smoke"] if smoke else [])
     if json_path:
-        with open(json_path, "w") as f:
-            json.dump(summary, f, indent=2)
-    return rows
+        args_list += ["--json", json_path]
+    return spawn_host_devices("benchmarks.pipeline_bench", args_list,
+                              "pipeline/")
 
 
 def main():
@@ -280,11 +248,11 @@ def main():
                     help="write the summary dict to this path")
     args = ap.parse_args()
 
-    from benchmarks.common import emit
+    from benchmarks.common import emit, platform_rows
     kw = {"steps": 5, "sleep_s": 0.03} if args.smoke else {}
     if args.core:
         rows, summary = bench_pipeline(**kw)
-        emit(rows)
+        emit(platform_rows(rows))
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(summary, f, indent=2)

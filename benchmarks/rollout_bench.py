@@ -153,40 +153,18 @@ def _realloc_rows(dim=1024, compute_s=0.4):
 
 
 def bench_realloc_overlap(n_devices: int = 4):
-    """Run the realloc-overlap iteration in a subprocess with forced host
-    devices so the reshard is a genuine multi-device collective; fall back
-    to in-process (however many devices exist) if spawning fails."""
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("PYTHONPATH", "")
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(here, "src"), here, env["PYTHONPATH"]])
-    try:
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.rollout_bench",
-             "--realloc-only"],
-            capture_output=True, text=True, env=env, timeout=600, cwd=here)
-        if r.returncode == 0:
-            rows = []
-            for line in r.stdout.splitlines():
-                parts = line.strip().split(",")
-                if len(parts) == 3 and parts[0].startswith("realloc/"):
-                    rows.append((parts[0], float(parts[1]), parts[2]))
-            if rows:
-                return rows
-    except Exception:  # noqa: BLE001 — fall through to in-process
-        pass
-    return _realloc_rows()
+    """Run the realloc-overlap iteration in a CPU child with forced host
+    devices so the reshard is a genuine multi-device collective; a failed
+    child fails the bench."""
+    from benchmarks.common import spawn_host_devices
+    return spawn_host_devices("benchmarks.rollout_bench", ["--realloc-only"],
+                              "realloc/", n_devices)
 
 
 def run():
-    return (bench_rollout()[0] + bench_bucketed() + bench_realloc_overlap())
+    from benchmarks.common import platform_rows
+    return (platform_rows(bench_rollout()[0] + bench_bucketed())
+            + bench_realloc_overlap())
 
 
 if __name__ == "__main__":
@@ -201,12 +179,12 @@ if __name__ == "__main__":
                          "core.profiler.fold_rollout_summary)")
     args = ap.parse_args()
 
-    from benchmarks.common import emit
+    from benchmarks.common import emit, platform_rows
     if args.realloc_only:
-        emit(_realloc_rows())
+        emit(platform_rows(_realloc_rows()))
     else:
         rows, summary = bench_rollout()
-        emit(rows + bench_bucketed() + bench_realloc_overlap())
+        emit(platform_rows(rows + bench_bucketed()) + bench_realloc_overlap())
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(summary, f, indent=2)

@@ -24,6 +24,8 @@ def main() -> None:
                     help="fewer MCMC iterations (CI-friendly)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (chaos_bench, estimator_acc, kernels_bench,
                             moe_bench, packed_bench, paper_figs,
                             pipeline_bench, roofline_table, rollout_bench,
@@ -53,16 +55,20 @@ def main() -> None:
     only = args.only.split(",") if args.only else list(benches)
 
     print("name,us_per_call,derived")
+    failed = []
     for name in only:
         t0 = time.time()
         try:
             rows = benches[name]()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
             continue
         for r, us, derived in rows:
             print(f"{r},{us:.1f},{derived}", flush=True)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmarks failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,13 +1,17 @@
 """Flash-decode: single-token attention over a (ring or linear) KV cache as a
 Pallas TPU kernel.
 
-One grid instance handles a whole GQA group — q is reshaped to
-(B, Hkv, G, D) so the (G x block_k) score tile feeds the MXU with all query
-heads of the group at once (G is small; the sublane dim pads to 8).  The KV
-cache streams through VMEM in (block_k x D) tiles along the innermost
-"arbitrary" grid axis with online-softmax scratch carry, and ``cache_len``
-masks unwritten slots — ring caches (window attention) are handled by the
-same bound since every resident slot is in-window by construction.
+One grid instance handles one batch row and all of its heads.  The cache
+keeps its model layout (B, C, Hkv, D), viewed as (B, C, Hkv*D) — a free
+reshape — so a KV tile is (block_k x Hkv*D): the sequence block on the
+sublane axis and every KV head together on the lane axis, which meets the
+(8, 128) tiling at any head_dim.  The query is spread block-diagonally to
+(Hq, Hkv*D) (query head h keeps its values in the lane slot of its KV head
+and zeros elsewhere), so one (Hq x Hkv*D) . (Hkv*D x block_k) product
+scores every head against its own KV head, and the PV product's diagonal
+slots are the per-head outputs.  ``cache_len`` arrives by scalar prefetch
+and masks unwritten slots — ring caches (window attention) are handled by
+the same bound since every resident slot is in-window by construction.
 """
 
 from __future__ import annotations
@@ -19,62 +23,91 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.flash_attention import (
+    LANES, NEG_INF, scores, softmax_finalize, softmax_init, softmax_update)
 
-LANES = 128
-NEG_INF = -2.0**30
+
+def spread_heads(q, hkv):
+    """(B, Hq, D) -> (B, Hq, Hkv*D), query head h's values in the lane slot
+    of its KV head h // (Hq/Hkv), zeros in the other slots."""
+    b, hq, d = q.shape
+    g = hq // hkv
+    eye = jnp.eye(hkv, dtype=q.dtype)[None, :, None, :, None]
+    return (q.reshape(b, hkv, g, 1, d) * eye).reshape(b, hq, hkv * d)
+
+
+def gather_heads(out, hkv):
+    """Inverse of ``spread_heads`` on the kernel output: keep each query
+    head's own KV-head slot.  (B, Hq, Hkv*D) -> (B, Hq, D)."""
+    b, hq, w = out.shape
+    d = w // hkv
+    o = out.reshape(b, hkv, hq // hkv, hkv, d)
+    return jnp.moveaxis(jnp.diagonal(o, axis1=1, axis2=3), -1, 1).reshape(
+        b, hq, d)
 
 
 def online_softmax_step(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                         scale, limit, k_start, step, n_steps):
     """One KV-tile step of the shared online-softmax decode body.
 
-    ``step``/``n_steps``: position in the innermost ("arbitrary") grid
-    axis; ``k_start``: logical position of this tile's first key;
-    ``limit``: number of valid keys for this row.  Initializes the scratch
-    carry on the first step, rescales the (max, sum, acc) carry on every
-    in-bounds tile, and writes the normalized output on the last step.
-    Shared by the contiguous (``flash_decode``) and block-table-paged
-    (``paged_flash_decode``) kernels — only how (limit, tile) are derived
-    differs between them."""
+    ``q_ref``: (1, Hq, W) spread query; ``k_ref``/``v_ref``: (1, bk, W)
+    tiles; ``step``/``n_steps``: position in the innermost ("arbitrary")
+    grid axis; ``k_start``: logical position of this tile's first key;
+    ``limit``: number of valid keys for this row.  Shared by the contiguous
+    (``flash_decode``) and block-table-paged (``paged_flash_decode``)
+    kernels — only how (limit, tile) are derived differs between them."""
     @pl.when(step == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        softmax_init(m_scr, l_scr, acc_scr)
 
     @pl.when(k_start < limit)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)      # (g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = scores(q_ref[0], k_ref[0], scale)  # (Hq, bk)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < limit, s, NEG_INF)
-
-        m_prev = m_scr[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_scr[:, 0] = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:, 0] = m_cur
+        softmax_update(jnp.where(kpos < limit, s, NEG_INF),
+                       v_ref[0].astype(jnp.float32), m_scr, l_scr, acc_scr)
 
     @pl.when(step == n_steps - 1)
     def _finalize():
-        l = l_scr[:, 0]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = softmax_finalize(l_scr, acc_scr).astype(o_ref.dtype)
+
+
+def decode_call(kernel, *, grid, num_scalar_prefetch, q_index, kv_index,
+                b, hq, width, block_k, dtype, interpret):
+    """The ``pallas_call`` shared by the contiguous and paged decode
+    kernels: a (b, kv-tile) grid over (1, Hq, W) queries/outputs and
+    (1, block_k, W) KV tiles, with (max, denom, acc) scratch."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, hq, width), q_index),
+            pl.BlockSpec((1, block_k, width), kv_index),
+            pl.BlockSpec((1, block_k, width), kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, hq, width), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((hq, LANES), jnp.float32),
+            pltpu.VMEM((hq, LANES), jnp.float32),
+            pltpu.VMEM((hq, width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, width), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             scale, block_k, n_k, cap):
-    ik = pl.program_id(2)
+    bb = pl.program_id(0)
+    ik = pl.program_id(1)
     online_softmax_step(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        scale=scale, limit=jnp.minimum(len_ref[0, 0], cap),
+                        scale=scale, limit=jnp.minimum(len_ref[bb], cap),
                         k_start=ik * block_k, step=ik, n_steps=n_k)
 
 
@@ -85,40 +118,26 @@ def flash_decode(q, k_cache, v_cache, *, cache_len, window=None, block_k=256,
     Returns (B, Hq, D)."""
     b, hq, d = q.shape
     _, cap, hkv, _ = k_cache.shape
-    g = hq // hkv
+    w = hkv * d
     block_k = min(block_k, cap)
     pad = (-cap) % block_k
+    kw = k_cache.reshape(b, cap, w)
+    vw = v_cache.reshape(b, cap, w)
     if pad:  # non-aligned caches: pad (masked by ``limit``); production
         # cache capacities are block-aligned so this is normally a no-op
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    n_k = pl.cdiv(cap, block_k)
-    scale = 1.0 / (d ** 0.5)
-    qg = q.reshape(b, hkv, g, d)
-    lens = cache_len.reshape(b, 1).astype(jnp.int32)
+        kw = jnp.pad(kw, ((0, 0), (0, pad), (0, 0)))
+        vw = jnp.pad(vw, ((0, 0), (0, pad), (0, 0)))
+    n_k = (cap + pad) // block_k
     # ring caches (window attention): every resident slot is valid
     eff_cap = cap if window is None else min(cap, window)
 
-    kernel = functools.partial(_kernel, scale=scale, block_k=block_k,
-                               n_k=n_k, cap=eff_cap)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, hkv, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, h, ik: (bb, 0)),
-            pl.BlockSpec((1, 1, g, d), lambda bb, h, ik: (bb, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda bb, h, ik: (bb, ik, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda bb, h, ik: (bb, ik, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, h, ik: (bb, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5),
+                               block_k=block_k, n_k=n_k, cap=eff_cap)
+    out = decode_call(
+        kernel, grid=(b, n_k), num_scalar_prefetch=1,
+        q_index=lambda bb, ik, lens: (bb, 0, 0),
+        kv_index=lambda bb, ik, lens: (bb, ik, 0),
+        b=b, hq=hq, width=w, block_k=block_k, dtype=q.dtype,
         interpret=interpret,
-    )(lens, qg, k_cache, v_cache)
-    return out.reshape(b, hq, d)
+    )(cache_len.astype(jnp.int32), spread_heads(q, hkv), kw, vw)
+    return gather_heads(out, hkv)

@@ -39,7 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 import numpy as np
 
-from repro.kernels.pallas_compat import CompilerParams
 from repro.kernels.ref import ACTS, expert_ids_of, group_metadata, row_tiles
 
 
@@ -104,7 +103,7 @@ def _forward(xs, group_sizes, w_gate, w_in, w_out, act, block_rows, block_ff,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*meta, xs, w_gate, w_in, w_out)

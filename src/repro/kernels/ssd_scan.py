@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 
 def _kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, d_ref, y_ref, st_ref,
@@ -104,7 +103,7 @@ def ssd_pallas(x, dt, a_log, b_mat, c_mat, d_vec, *, chunk, init_state=None,
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a_log, b_mat, c_mat, d_vec)

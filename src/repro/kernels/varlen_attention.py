@@ -33,10 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
-LANES = 128
-NEG_INF = -2.0**30
+from repro.kernels.flash_attention import (
+    LANES, NEG_INF, scores, softmax_finalize, softmax_init, softmax_update)
 
 
 def _kernel(cu_ref, qlo_ref, qhi_ref, klo_ref, khi_ref, q_ref, k_ref, v_ref,
@@ -47,9 +45,7 @@ def _kernel(cu_ref, qlo_ref, qhi_ref, klo_ref, khi_ref, q_ref, k_ref, v_ref,
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        softmax_init(m_scr, l_scr, acc_scr)
 
     q_start = iq * block
     k_start = ik * block
@@ -66,14 +62,9 @@ def _kernel(cu_ref, qlo_ref, qhi_ref, klo_ref, khi_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[:, 0, :].astype(jnp.float32)  # (block, d)
-        k = k_ref[:, 0, :].astype(jnp.float32)
-        v = v_ref[:, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        s = scores(q_ref[0], k_ref[0], scale)  # (block, block)
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
 
         def seg_of(pos):
             # segment id = #sequence starts at or before pos; positions at
@@ -83,33 +74,17 @@ def _kernel(cu_ref, qlo_ref, qhi_ref, klo_ref, khi_ref, q_ref, k_ref, v_ref,
             return jax.lax.fori_loop(1, n_seq + 1, body,
                                      jnp.zeros(pos.shape, jnp.int32))
 
-        seg_q = seg_of(q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0))
-        seg_k = seg_of(k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block), 1))
-        mask = seg_q == seg_k  # (block, block) block-diagonal
+        mask = seg_of(qpos) == seg_of(kpos)  # (block, block) block-diagonal
         if causal:
             mask = jnp.logical_and(mask, kpos <= qpos)
         if window is not None:
             mask = jnp.logical_and(mask, qpos - kpos < window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_cur = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
+        softmax_update(jnp.where(mask, s, NEG_INF),
+                       v_ref[0].astype(jnp.float32), m_scr, l_scr, acc_scr)
 
     @pl.when(ik == n_k - 1)
     def _finalize():
-        l = l_scr[:, 0]
-        l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-        o_ref[:, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = softmax_finalize(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block",
@@ -126,13 +101,13 @@ def flash_mha_varlen(q, k, v, cu_seqlens, *, causal=True, window=None,
     n_seq = cu_seqlens.shape[0] - 1
     block = min(block, -(-t // 8) * 8)
     pad = (-t) % block
+    # heads in front of tokens: (H, T, D) blocks are (block x head_dim)
+    qt, kt, vt = (jnp.swapaxes(x, 0, 1) for x in (q, k, v))
     if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-        k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
+        qt, kt, vt = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                      for x in (qt, kt, vt))
     t_pad = t + pad
     n = t_pad // block
-    scale = 1.0 / (d ** 0.5)
 
     # per-tile segment ranges for the block-level skip (host-side jnp;
     # they ride in as scalar prefetch alongside cu_seqlens itself)
@@ -142,24 +117,21 @@ def flash_mha_varlen(q, k, v, cu_seqlens, *, causal=True, window=None,
     seg_lo = jnp.searchsorted(cu[1:], starts, side="right").astype(jnp.int32)
     seg_hi = jnp.searchsorted(cu[1:], ends, side="right").astype(jnp.int32)
 
-    kernel = functools.partial(_kernel, scale=scale, block=block, n_k=n,
-                               n_seq=n_seq, causal=causal, window=window)
+    kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5), block=block,
+                               n_k=n, n_seq=n_seq, causal=causal,
+                               window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(hq, n, n),
         in_specs=[
-            pl.BlockSpec((block, 1, d),
-                         lambda h, iq, ik, cu, ql, qh, kl, kh: (iq, h, 0)),
-            pl.BlockSpec((block, 1, d),
-                         lambda h, iq, ik, cu, ql, qh, kl, kh, g=g:
-                         (ik, h // g, 0)),
-            pl.BlockSpec((block, 1, d),
-                         lambda h, iq, ik, cu, ql, qh, kl, kh, g=g:
-                         (ik, h // g, 0)),
+            pl.BlockSpec((1, block, d), lambda h, iq, ik, *_: (h, iq, 0)),
+            pl.BlockSpec((1, block, d),
+                         lambda h, iq, ik, *_, g=g: (h // g, ik, 0)),
+            pl.BlockSpec((1, block, d),
+                         lambda h, iq, ik, *_, g=g: (h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (block, 1, d),
-            lambda h, iq, ik, cu, ql, qh, kl, kh: (iq, h, 0)),
+        out_specs=pl.BlockSpec((1, block, d),
+                               lambda h, iq, ik, *_: (h, iq, 0)),
         scratch_shapes=[
             pltpu.VMEM((block, LANES), jnp.float32),  # running max
             pltpu.VMEM((block, LANES), jnp.float32),  # running denom
@@ -169,9 +141,9 @@ def flash_mha_varlen(q, k, v, cu_seqlens, *, causal=True, window=None,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t_pad, hq, d), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((hq, t_pad, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(cu, seg_lo, seg_hi, seg_lo, seg_hi, q, k, v)
-    return out[:t] if pad else out
+    )(cu, seg_lo, seg_hi, seg_lo, seg_hi, qt, kt, vt)
+    return jnp.swapaxes(out[:, :t], 0, 1)

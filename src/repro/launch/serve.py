@@ -644,8 +644,10 @@ def main():
     import jax
     import numpy as np
     from repro.configs import ARCHS
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
 
+    enable_compile_cache()
     cfg = ARCHS[args.arch]
     if args.smoke:
         cfg = cfg.reduced()

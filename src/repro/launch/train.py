@@ -1,24 +1,26 @@
 """Cluster launcher: search an execution plan and run RLHF training.
 
-Single-host entry point (this container); on a real fleet each host runs the
-same command under its own process index and ``jax.distributed.initialize()``
-stitches the global device mesh — the plan/search/runtime layers are
-device-count agnostic.
+Single-host entry point; on a real fleet each host runs the same command
+under its own process index and ``jax.distributed.initialize()`` stitches
+the global device mesh — the plan/search/runtime layers are device-count
+agnostic.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
         --algo ppo --steps 5 [--nodes 2 --devs-per-node 8]
     PYTHONPATH=src python -m repro.launch.train --plan-only --arch llama-7b \
         --nodes 2 --devs-per-node 8 --h100
+
+``build_experiment`` and ``train`` are the build-and-run the command line
+drives; ``chip_smoke.py`` calls the same two functions.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--algo", default="ppo", choices=["ppo"])
@@ -30,6 +32,12 @@ def main():
     ap.add_argument("--devs-per-node", type=int, default=1)
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (CPU-sized)")
+    ap.add_argument("--rollout-impl", default=None,
+                    choices=["reference", "pallas", "pallas_interpret"],
+                    help="kernel tier of the rollout (default: training's)")
+    ap.add_argument("--opt-state-dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="AdamW m/v dtype (bfloat16 halves them)")
     ap.add_argument("--h100", action="store_true",
                     help="cost-model the paper's H100 cluster")
     ap.add_argument("--plan-only", action="store_true",
@@ -38,16 +46,17 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--distributed", action="store_true",
                     help="multi-host: call jax.distributed.initialize()")
-    args = ap.parse_args()
+    return ap
 
-    if args.distributed:
-        import jax
-        jax.distributed.initialize()
 
-    import jax
+def build_experiment(args):
+    """The PPO experiment the arguments describe: ``--arch`` as actor and
+    critic (ref/reward are copies), on a ``--nodes`` x ``--devs-per-node``
+    cluster."""
     from repro import hw
     from repro.configs import ARCHS
     from repro.core.plan import Cluster
+    from repro.optim.adamw import AdamWConfig
     from repro.rlhf.experiment import ExperimentConfig, RLHFExperiment
     from repro.rlhf.ppo import PPOHyperparameters
 
@@ -61,33 +70,64 @@ def main():
                       **kw)
     exp_cfg = ExperimentConfig(
         batch=args.batch, prompt_len=args.prompt_len, gen_len=args.gen_len,
-        search_iters=args.search_iters,
+        search_iters=args.search_iters, rollout_impl=args.rollout_impl,
+        opt=AdamWConfig(state_dtype=args.opt_state_dtype),
         ppo=PPOHyperparameters(n_minibatches=min(2, args.batch)))
-
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
-          f"cluster={args.nodes}x{args.devs_per_node}")
-    run = RLHFExperiment(cfg, cfg, cluster, exp_cfg)
-    print(run.plan)
-    if args.plan_only:
-        return
+          f"cluster={args.nodes}x{args.devs_per_node}", flush=True)
+    return RLHFExperiment(cfg, cfg, cluster, exp_cfg)
+
+
+def train(run, steps: int, *, ckpt=None) -> list[dict]:
+    """Run ``steps`` PPO iterations; returns one record per iteration
+    (wall seconds, actor/critic loss, reward mean, and ``kl``: the masked
+    mean of rollout minus reference logprobs).  Every record's values are
+    host floats, so each iteration's time covers its device work."""
+    import jax
+    import jax.numpy as jnp
 
     mgr = None
-    if args.ckpt:
+    if ckpt:
         from repro.checkpoint.manager import CheckpointManager
-        mgr = CheckpointManager(args.ckpt)
-
-    for step in range(args.steps):
-        t0 = time.time()
+        mgr = CheckpointManager(ckpt)
+    records = []
+    for step in range(steps):
+        t0 = time.perf_counter()
         out = run.run_iteration(jax.random.PRNGKey(step))
-        print(f"step {step}: {time.time()-t0:.1f}s "
-              f"actor_loss={out['actor_stats']['loss']:+.4f} "
-              f"reward={float(out['rewards'].mean()):+.3f}", flush=True)
+        mask = out["gen_mask"]
+        rec = {"step": step,
+               "actor_loss": float(out["actor_stats"]["loss"]),
+               "critic_loss": float(out["critic_stats"]["loss"]),
+               "reward_mean": float(out["rewards"].mean()),
+               "kl": float(jnp.sum((out["logp"] - out["ref_logp"]) * mask)
+                           / jnp.maximum(jnp.sum(mask), 1.0))}
+        rec["seconds"] = time.perf_counter() - t0
+        records.append(rec)
+        print(f"step {step}: {rec['seconds']:.1f}s "
+              f"actor_loss={rec['actor_loss']:+.4f} "
+              f"reward={rec['reward_mean']:+.3f}", flush=True)
         if mgr and (step + 1) % 5 == 0:
             mgr.save_async(step + 1, {
                 "actor": run.models["actor"].params,
                 "critic": run.models["critic"].params})
     if mgr:
         mgr.wait()
+    return records
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    import jax
+    if args.distributed:
+        jax.distributed.initialize()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+    run = build_experiment(args)
+    print(run.plan)
+    if args.plan_only:
+        return
+    train(run, args.steps, ckpt=args.ckpt)
     print("done")
 
 

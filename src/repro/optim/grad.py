@@ -67,8 +67,7 @@ def compressed_psum(g, axis_name: str, error: jax.Array | None = None):
     re-adds it next step, making compression unbiased over time.
 
     Returns (mean_gradient, new_error); shapes match ``g``."""
-    from repro.parallel.compat import axis_size
-    k = axis_size(axis_name)
+    k = jax.lax.axis_size(axis_name)
     orig_shape = g.shape
     g32 = g.astype(jnp.float32).reshape(-1)
     if error is not None:

@@ -189,8 +189,19 @@ def test_capacity_is_cohort_dependent_when_overflowing():
     dispatch removes (otherwise the regression tests above test nothing)."""
     cfg = _moe_cfg(moe_dispatch="capacity")
     p = M.moe_init(RNG, cfg)
-    x = jax.random.normal(jax.random.PRNGKey(3), (4, 16, cfg.d_model),
-                          jnp.float32)
+    # overflow by construction: router column 0 reads a unit direction u
+    # with gain 10, and every token carries exactly 3u plus noise
+    # orthogonal to u, so expert 0's logit is 30 for every token while
+    # |x . w_j| <= |x| |w_j| stays far below it for the random columns —
+    # all 64 tokens pick expert 0 against a capacity of 40
+    u = jnp.ones((cfg.d_model,), jnp.float32) / cfg.d_model ** 0.5
+    p["router"]["w"] = p["router"]["w"].at[:, 0].set(10.0 * u)
+    noise = jax.random.normal(jax.random.PRNGKey(3), (4, 16, cfg.d_model),
+                              jnp.float32)
+    x = noise - (noise @ u)[..., None] * u + 3.0 * u
+    _, _, top_i = M._router(p, cfg, x.reshape(-1, cfg.d_model))
+    assert bool(jnp.all(top_i[:, 0] == 0))
+    assert 64 > M.capacity(64, cfg)
     full, _ = M.moe_apply(p, cfg, x)
     single = jnp.stack([M.moe_apply(p, cfg, x[b:b + 1, s:s + 1])[0][0, 0]
                         for b in range(4) for s in range(16)])

@@ -1,9 +1,5 @@
 """Multi-device behaviours that need >1 XLA device: run in subprocesses with
-their own XLA_FLAGS (the main test process keeps the 1-device view).
-
-Mesh construction goes through ``repro.parallel.compat.make_mesh`` so the
-same tests run on the pinned jax 0.4.37 (no ``jax.sharding.AxisType``) and
-on >= 0.5 (explicit ``Auto`` axis types)."""
+their own XLA_FLAGS (the main test process keeps the 1-device view)."""
 
 import os
 import subprocess
@@ -32,9 +28,9 @@ def test_reshard_preserves_values_across_shardings():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.parallel.realloc_exec import reshard
 
-        from repro.parallel.compat import auto_axis_types, make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"),
-                         axis_types=auto_axis_types(2))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         x = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
         a = jax.device_put(x, NamedSharding(mesh, P("data", "model")))
         tree = {"w": a, "b": jax.device_put(x[:, 0], NamedSharding(mesh, P("data")))}
@@ -70,9 +66,9 @@ def test_tp_sharded_train_step_matches_single_device():
         # single device
         p1, o1, m1 = jax.jit(step)(p, opt, batch)
 
-        from repro.parallel.compat import auto_axis_types, make_mesh
-        mesh = make_mesh((2, 2), ("data", "model"),
-                         axis_types=auto_axis_types(2))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         rules = SH.ShardingRules()
         psh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                            SH.param_specs(p, rules))
@@ -99,8 +95,9 @@ def test_pipeline_parallel_matches_sequential():
     out = run_with_devices("""
         import jax, jax.numpy as jnp
         from repro.parallel.pipeline import pipeline_apply, microbatch
-        from repro.parallel.compat import auto_axis_types, make_mesh
-        mesh = make_mesh((4,), ("stage",), axis_types=auto_axis_types(1))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("stage",),
+                             axis_types=(AxisType.Auto,))
         rng = jax.random.PRNGKey(0)
         L, D, B, MBS = 8, 16, 12, 6
         ws = jax.random.normal(rng, (L, D, D)) * 0.3
@@ -129,7 +126,7 @@ def test_ep_sharded_dropless_moe_matches_single_device():
         from repro.configs import ARCHS
         from repro.models import forward, init_params, synth_batch
         from repro.parallel import sharding as SH
-        from repro.parallel.compat import auto_axis_types, make_mesh
+        from jax.sharding import AxisType
 
         cfg = ARCHS["granite-moe-1b-a400m"].reduced()
         assert cfg.moe_dispatch == "dropless" and cfg.n_experts == 4
@@ -138,8 +135,8 @@ def test_ep_sharded_dropless_moe_matches_single_device():
         fwd = lambda p, b: forward(p, cfg, b, remat=False)
         h1, _ = jax.jit(fwd)(p, batch)
 
-        mesh = make_mesh((2, 2), ("data", "model"),
-                         axis_types=auto_axis_types(2))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         rules = SH.ShardingRules()
         specs = SH.param_specs(p, rules)
         # the expert axis of the stacked (L, E, D, F) weights rides the
@@ -166,8 +163,9 @@ def test_compressed_psum_error_feedback():
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.grad import compressed_psum
-        from repro.parallel.compat import auto_axis_types, make_mesh
-        mesh = make_mesh((4,), ("dp",), axis_types=auto_axis_types(1))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("dp",),
+                             axis_types=(AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 256))
 
         def f(gs, err):

@@ -91,6 +91,28 @@ def test_paged_decode_masks_unallocated_slots():
     np.testing.assert_allclose(np.asarray(out), np.asarray(base), atol=2e-6)
 
 
+@pytest.mark.parametrize("starts", [(0, 9, 30), (12, 3, 35)])
+def test_paged_verify_matches_reference_at_offset_positions(starts):
+    """The speculative verify step's kernel tier honours the explicit
+    query positions: K verify tokens starting mid-cache attend exactly the
+    logical positions <= their own, as the reference does."""
+    b, kk, hq, hkv, d, bs, m = 3, 5, 8, 2, 16, 8, 5
+    n = 1 + b * m
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (b, kk, hq, d), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (n, bs, hkv, d), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (n, bs, hkv, d), jnp.float32)
+    perm = np.random.default_rng(1).permutation(np.arange(1, n))
+    tbl = jnp.asarray(perm.reshape(b, m), jnp.int32)
+    pos = (jnp.asarray(starts, jnp.int32)[:, None]
+           + jnp.arange(kk, dtype=jnp.int32)[None])
+    ref = ops.paged_verify_mha(q, k_pool, v_pool, tbl, q_positions=pos,
+                               impl="reference")
+    out = ops.paged_verify_mha(q, k_pool, v_pool, tbl, q_positions=pos,
+                               impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
 # ----------------------------------------------------------------- serving
 
 @pytest.fixture(scope="module")

@@ -41,33 +41,36 @@ def test_bucketed_generator_cache_keys_sampling_attrs():
     a stale hit would silently decode with the old settings."""
     cfg = ARCHS["qwen2-0.5b"].reduced()
     params = MDL.init_params(RNG, cfg)
-    gen = MDL.BucketedGenerator(cfg, temperature=1.0)
+    # a flat, high-temperature draw over 16 steps x 8 rows against the
+    # greedy (top_k=1) stream: equal streams would need 128 draws from a
+    # near-uniform distribution over the vocab to hit the argmax each time
+    gen = MDL.BucketedGenerator(cfg, temperature=100.0)
     batch = MDL.synth_batch(jax.random.PRNGKey(1), cfg, 8, 2, "prompt")
-    out0 = gen(params, batch, num_new_tokens=8, rng=jax.random.PRNGKey(2))
+    out0 = gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 1
 
     gen.top_k = 1  # greedy-equivalent truncation: observably different
-    out1 = gen(params, batch, num_new_tokens=8, rng=jax.random.PRNGKey(2))
+    out1 = gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 2, "top_k change must miss the jit cache"
-    g = MDL.generate(params, cfg, batch, num_new_tokens=8, rng=None)
+    g = MDL.generate(params, cfg, batch, num_new_tokens=16, rng=None)
     np.testing.assert_array_equal(np.asarray(out1["tokens"]),
                                   np.asarray(g["tokens"]))
     assert not np.array_equal(np.asarray(out0["tokens"]),
                               np.asarray(out1["tokens"]))
 
     gen.sampler = "gumbel"
-    gen(params, batch, num_new_tokens=8, rng=jax.random.PRNGKey(2))
+    gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 3, "sampler change must miss the jit cache"
     gen.eos_id = 3
-    gen(params, batch, num_new_tokens=8, rng=jax.random.PRNGKey(2))
+    gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 4, "eos_id change must miss the jit cache"
     gen.top_p = 0.9
-    gen(params, batch, num_new_tokens=8, rng=jax.random.PRNGKey(2))
+    gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 5, "top_p change must miss the jit cache"
 
     hits = gen.hits
     gen.top_k, gen.sampler, gen.eos_id, gen.top_p = 1, "cdf", None, 1.0
-    gen(params, batch, num_new_tokens=8, rng=jax.random.PRNGKey(2))
+    gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 5 and gen.hits == hits + 1  # old key still cached
 
 
