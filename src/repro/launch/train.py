@@ -80,9 +80,11 @@ def build_experiment(args):
 
 def train(run, steps: int, *, ckpt=None) -> list[dict]:
     """Run ``steps`` PPO iterations; returns one record per iteration
-    (wall seconds, actor/critic loss, reward mean, and ``kl``: the masked
-    mean of rollout minus reference logprobs).  Every record's values are
-    host floats, so each iteration's time covers its device work."""
+    (wall seconds, actor/critic loss, reward mean, ``kl``: the masked mean
+    of rollout minus reference logprobs, ``logp_gap``: the masked mean of
+    their absolute difference, and ``logp_mean``: the masked mean rollout
+    logprob).  Every record's values are host floats, so each iteration's
+    time covers its device work."""
     import jax
     import jax.numpy as jnp
 
@@ -95,12 +97,16 @@ def train(run, steps: int, *, ckpt=None) -> list[dict]:
         t0 = time.perf_counter()
         out = run.run_iteration(jax.random.PRNGKey(step))
         mask = out["gen_mask"]
+        masked_mean = lambda x: float(  # noqa: E731
+            jnp.sum(x * mask) / jnp.maximum(jnp.sum(mask), 1.0))
+        diff = out["logp"] - out["ref_logp"]
         rec = {"step": step,
                "actor_loss": float(out["actor_stats"]["loss"]),
                "critic_loss": float(out["critic_stats"]["loss"]),
                "reward_mean": float(out["rewards"].mean()),
-               "kl": float(jnp.sum((out["logp"] - out["ref_logp"]) * mask)
-                           / jnp.maximum(jnp.sum(mask), 1.0))}
+               "kl": masked_mean(diff),
+               "logp_gap": masked_mean(jnp.abs(diff)),
+               "logp_mean": masked_mean(out["logp"])}
         rec["seconds"] = time.perf_counter() - t0
         records.append(rec)
         print(f"step {step}: {rec['seconds']:.1f}s "

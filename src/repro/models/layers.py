@@ -88,8 +88,13 @@ def mlp_apply(p, cfg: ModelConfig, x):
 # ----------------------------------------------------------------- Embedding
 
 def embed_init(key, cfg: ModelConfig):
+    """A tied table is also the LM head, so it is drawn at the head's
+    d_model**-0.5: at std 1 the logits would have std ~sqrt(d_model) and
+    every sampled token a logprob of ~0."""
     dt = jnp.dtype(cfg.dtype)
-    p = {"table": truncated_normal(key, (cfg.vocab_size, cfg.d_model), dt, 1.0)}
+    scale = cfg.d_model ** -0.5 if cfg.tie_embeddings else 1.0
+    p = {"table": truncated_normal(key, (cfg.vocab_size, cfg.d_model), dt,
+                                   scale)}
     return p
 
 

@@ -29,10 +29,13 @@ def test_launcher_plumbs_rollout_tier_and_opt_state_dtype():
     assert [r["step"] for r in records] == [0, 1]
     for r in records:
         for key in ("seconds", "actor_loss", "critic_loss", "reward_mean",
-                    "kl"):
+                    "kl", "logp_gap", "logp_mean"):
             assert math.isfinite(r[key]), (key, r)
-    # the rollout policy is the reference policy at iteration 0
+    # the rollout policy is the reference policy at iteration 0, and its
+    # sampled tokens carry real (far from 0) logprobs
     assert abs(records[0]["kl"]) < 1e-3
+    assert records[0]["logp_gap"] < 1e-3
+    assert records[0]["logp_mean"] < -0.5 * math.log(run.actor_cfg.vocab_size)
 
 
 def _cache_dir_in_child(env_dir):

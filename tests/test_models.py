@@ -93,6 +93,21 @@ def test_generate_shapes():
     assert bool(jnp.all(out["logprobs"] <= 0))
 
 
+@pytest.mark.parametrize(
+    "arch", [a for a in ASSIGNED if ARCHS[a].tie_embeddings])
+def test_sampled_logprobs_at_init_are_not_degenerate(arch, reduced):
+    """A tied table doubles as the LM head: drawn at the head's scale the
+    logits are of unit order, so tokens sampled from a fresh model carry
+    logprobs near -log(vocab).  Drawn at std 1 they sit near 0, which
+    leaves every rollout-vs-reference logprob check empty."""
+    cfg = reduced[arch]
+    p = init_params(RNG, cfg)
+    batch = synth_batch(RNG, cfg, 8, 4, "prefill")
+    out = generate(p, cfg, batch, num_new_tokens=8, rng=RNG)
+    mean = float(out["logprobs"].mean())
+    assert mean < -0.5 * np.log(cfg.vocab_size), mean
+
+
 def test_value_head():
     cfg = ARCHS["qwen2-0.5b"].reduced()
     p = init_params(RNG, cfg, head="value")

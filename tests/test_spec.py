@@ -45,7 +45,9 @@ def test_bucketed_generator_cache_keys_sampling_attrs():
     # greedy (top_k=1) stream: equal streams would need 128 draws from a
     # near-uniform distribution over the vocab to hit the argmax each time
     gen = MDL.BucketedGenerator(cfg, temperature=100.0)
-    batch = MDL.synth_batch(jax.random.PRNGKey(1), cfg, 8, 2, "prompt")
+    # a bucket-sized prompt is not left-padded, so the generator's greedy
+    # stream is the one ``generate`` gives on the same prompt
+    batch = MDL.synth_batch(jax.random.PRNGKey(1), cfg, 16, 2, "prompt")
     out0 = gen(params, batch, num_new_tokens=16, rng=jax.random.PRNGKey(2))
     assert gen.compiles == 1
 
