@@ -1,0 +1,12 @@
+"""Share of the traced window in which no operation ran on the device
+(union of the device's operation intervals, averaged over chips)."""
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "tokens_per_s"
+
+
+def read(ctx):
+    if ctx.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
