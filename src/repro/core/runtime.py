@@ -103,7 +103,7 @@ import dataclasses
 import time
 from typing import Any, Callable, Optional
 
-from repro.core import fault
+from repro.core import fault, tracing
 from repro.core.dfg import (DataflowGraph, FunctionCall, GENERATE, INFERENCE,
                             TRAIN, base_name, iteration_of,
                             unroll_iterations)
@@ -157,6 +157,10 @@ class CallRecord:
     attempts: int = 1  # executions including retries (retried == attempts > 1)
     speculated: bool = False  # a duplicate was raced on an idle mesh
     spec_won: bool = False  # ... and the duplicate finished first
+    # JAX compile events in the call's executor thread (core/tracing.py)
+    traces: int = 0
+    lowerings: int = 0
+    compile_s: float = 0.0
 
 
 class RuntimeEngine:
@@ -267,6 +271,8 @@ class RuntimeEngine:
         self._recorded_upto = 0  # records already folded into the cost model
         self._template = None  # cached (intra, cross) dependency structure
         self.records: list[CallRecord] = []
+        tracing.install()
+        self._outside0 = tracing.outside()  # "(outside)" counts from here
         self._dev_locks: dict[int, asyncio.Lock] = {}
         self._model_locks: dict[str, asyncio.Lock] = {}
         self._model_users: dict[str, int] = {}
@@ -920,20 +926,24 @@ class RuntimeEngine:
         # preemption notices are consumed before the call binds to a mesh:
         # a replan here keeps new admissions off the doomed host
         await self._poll_preemptions()
-        for p in intra[call.name]:
-            await self._wait_dep(done[f"{p}@{t}"])
-        if t > 0:  # version edges into the previous iteration
-            for p in cross[call.name]:
-                await self._wait_dep(done[f"{p}@{t - 1}"])
+        abs_iter = self._iter_base + t
+        with tracing.span("rt.wait", call=call.name, iteration=abs_iter):
+            for p in intra[call.name]:
+                await self._wait_dep(done[f"{p}@{t}"])
+            if t > 0:  # version edges into the previous iteration
+                for p in cross[call.name]:
+                    await self._wait_dep(done[f"{p}@{t - 1}"])
+            locks = await self._locks_for(call.name)
+            for lk in locks:  # deterministic (device-id) order: no deadlock
+                await lk.acquire()
         data = pools[t]
-        locks = await self._locks_for(call.name)
-        for lk in locks:  # deterministic (device-id) order: no deadlock
-            await lk.acquire()
         try:
             self._check_abort()
-            realloc_s, prefetch_hit, cross_hit, moved = \
-                await self._maybe_reallocate(call)
-            moved += await self._maybe_reallocate_opt(call)
+            with tracing.span("rt.realloc", call=call.name) as sp:
+                realloc_s, prefetch_hit, cross_hit, moved = \
+                    await self._maybe_reallocate(call)
+                moved += await self._maybe_reallocate_opt(call)
+                sp.set_metadata(bytes=moved)
             self._check_abort()
             policy = self.retry_policy.for_call_type(call.call_type)
             factor = (policy.straggler_factor
@@ -949,14 +959,17 @@ class RuntimeEngine:
 
             fn = self.executors.get(call.name) \
                 or self.executors[base_name(call.name)]
-            abs_iter = self._iter_base + t
+            compiles = tracing.Compiles()  # summed over attempts
 
             def work():
-                # chaos injection fires in the executor thread, exactly
-                # where a real device fault would surface
-                if self.fault_injector is not None:
-                    self.fault_injector.on_execute(call.name, abs_iter)
-                return fn(self.models[call.model_name], inputs)
+                with tracing.attribute(compiles), tracing.span(
+                        "rt.exec", call=call.name, iteration=abs_iter,
+                        attempt=attempts):
+                    # chaos injection fires in the executor thread, exactly
+                    # where a real device fault would surface
+                    if self.fault_injector is not None:
+                        self.fault_injector.on_execute(call.name, abs_iter)
+                    return fn(self.models[call.model_name], inputs)
 
             async def execute():
                 self._begin_use(call.model_name)
@@ -1003,10 +1016,12 @@ class RuntimeEngine:
             data.update(out or {})
             self.records.append(CallRecord(
                 call.name, t0, t1, realloc_s, straggled, retried,
-                prefetch_hit, iteration=self._iter_base + t,
+                prefetch_hit, iteration=abs_iter,
                 realloc_bytes=moved, prefetch_cross=cross_hit,
                 attempts=attempts, speculated=spec["dispatched"],
-                spec_won=spec["won"]))
+                spec_won=spec["won"], traces=compiles.traces,
+                lowerings=compiles.lowerings,
+                compile_s=compiles.compile_s))
         finally:
             for lk in reversed(locks):
                 lk.release()
@@ -1063,6 +1078,13 @@ class RuntimeEngine:
         self._abort_ev = asyncio.Event()
 
         async def run_iter(t: int):
+            # one step event from admission to retirement; at depth > 1 the
+            # iterations' events overlap on the loop thread
+            with tracing.step_span("rt.iteration",
+                                   step_num=self._iter_base + t):
+                await run_and_retire(t)
+
+        async def run_and_retire(t: int):
             try:
                 res = await asyncio.gather(*(
                     self._run_call(c, t, pools, done, intra, cross,
@@ -1087,35 +1109,10 @@ class RuntimeEngine:
                         lambda: state["failed"] or state["retired"] == t)
                     if state["failed"]:
                         return
-                    # safe point: retire drained (preemption-noticed) hosts
-                    # BEFORE the pool pops — a deadline expiry raised here
-                    # replays this retirement cleanly after recovery
-                    await self._poll_preemptions()
-                    await self._finalize_migration()
-                    pool = pools.pop(t)
-                    if keep_pools:
-                        results[t] = pool
-                    self.iterations_done += 1
-                    if on_retire is not None:
-                        if quiesce_on_retire:
-                            # drain running executors first: a hook that
-                            # snapshots model state (checkpointing) must
-                            # never read buffers a concurrent train step
-                            # donated.  The hook itself runs synchronously
-                            # in the loop thread, so no new call can start
-                            # underneath it.
-                            for m in self.models:
-                                await self._await_model_idle(m)
-                        on_retire(self._iter_base + t, pool)
-                    if (self.recalibrate_every > 0 and self.cost is not None
-                            and len(self.records) - self._recorded_upto
-                            >= self.recalibrate_every):
-                        self.recalibrate()
-                    if self._pending_gain and self.replanner is not None:
-                        # device gain is consumed at retirement: grow the
-                        # mesh and replan; weights reshard lazily on each
-                        # model's next call
-                        self._apply_gain()
+                    with tracing.span("rt.retire",
+                                      iteration=self._iter_base + t):
+                        await self._retire(t, pools, results, keep_pools,
+                                           on_retire, quiesce_on_retire)
                     state["retired"] = t + 1
                     carry["retired"] = t + 1
                     retire_cond.notify_all()
@@ -1189,6 +1186,35 @@ class RuntimeEngine:
                 for name in self.models:
                     await self._drain_prefetch(name, fold=False)
         return results
+
+    async def _retire(self, t: int, pools: dict, results: list,
+                      keep_pools: bool, on_retire, quiesce_on_retire: bool):
+        # safe point: retire drained (preemption-noticed) hosts BEFORE the
+        # pool pops — a deadline expiry raised here replays this retirement
+        # cleanly after recovery
+        await self._poll_preemptions()
+        await self._finalize_migration()
+        pool = pools.pop(t)
+        if keep_pools:
+            results[t] = pool
+        self.iterations_done += 1
+        if on_retire is not None:
+            if quiesce_on_retire:
+                # drain running executors first: a hook that snapshots model
+                # state (checkpointing) must never read buffers a concurrent
+                # train step donated.  The hook itself runs synchronously in
+                # the loop thread, so no new call can start underneath it.
+                for m in self.models:
+                    await self._await_model_idle(m)
+            on_retire(self._iter_base + t, pool)
+        if (self.recalibrate_every > 0 and self.cost is not None
+                and len(self.records) - self._recorded_upto
+                >= self.recalibrate_every):
+            self.recalibrate()
+        if self._pending_gain and self.replanner is not None:
+            # device gain is consumed at retirement: grow the mesh and
+            # replan; weights reshard lazily on each model's next call
+            self._apply_gain()
 
     def run(self, initial_data, steps: int = 1, *,
             pipeline_depth: Optional[int] = None,
@@ -1488,12 +1514,23 @@ class RuntimeEngine:
             # aggregate by base name: unrolled ``name@t`` records of one call
             # fold into a single row
             agg = calls.setdefault(base_name(r.name),
-                                   {"count": 0, "total_s": 0.0})
+                                   {"count": 0, "total_s": 0.0, "traces": 0,
+                                    "lowerings": 0, "compile_s": 0.0})
             agg["count"] += 1
             agg["total_s"] += r.end - r.start
+            agg["traces"] += r.traces
+            agg["lowerings"] += r.lowerings
+            agg["compile_s"] += r.compile_s
         for agg in calls.values():
             agg["total_s"] = round(agg["total_s"], 4)
             agg["mean_s"] = round(agg["total_s"] / agg["count"], 4)
+        # compiles in no call's executor (realloc, the caller's own jits)
+        # since this engine was built
+        out = tracing.outside().minus(
+            getattr(self, "_outside0", tracing.Compiles()))
+        calls["(outside)"] = {"traces": out.traces,
+                              "lowerings": out.lowerings,
+                              "compile_s": out.compile_s}
         return {
             "wall_s": max(r.end for r in self.records) - t0,
             "realloc_s": sum(r.realloc_s for r in self.records),

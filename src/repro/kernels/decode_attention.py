@@ -72,11 +72,12 @@ def online_softmax_step(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0] = softmax_finalize(l_scr, acc_scr).astype(o_ref.dtype)
 
 
-def decode_call(kernel, *, grid, num_scalar_prefetch, q_index, kv_index,
-                b, hq, width, block_k, dtype, interpret):
+def decode_call(kernel, *, name, grid, num_scalar_prefetch, q_index,
+                kv_index, b, hq, width, block_k, dtype, interpret):
     """The ``pallas_call`` shared by the contiguous and paged decode
     kernels: a (b, kv-tile) grid over (1, Hq, W) queries/outputs and
-    (1, block_k, W) KV tiles, with (max, denom, acc) scratch."""
+    (1, block_k, W) KV tiles, with (max, denom, acc) scratch.  ``name``
+    names the kernel in the compiled program and in device traces."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=grid,
@@ -99,6 +100,7 @@ def decode_call(kernel, *, grid, num_scalar_prefetch, q_index, kv_index,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )
 
 
@@ -134,7 +136,7 @@ def flash_decode(q, k_cache, v_cache, *, cache_len, window=None, block_k=256,
     kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5),
                                block_k=block_k, n_k=n_k, cap=eff_cap)
     out = decode_call(
-        kernel, grid=(b, n_k), num_scalar_prefetch=1,
+        kernel, name="flash_decode", grid=(b, n_k), num_scalar_prefetch=1,
         q_index=lambda bb, ik, lens: (bb, 0, 0),
         kv_index=lambda bb, ik, lens: (bb, ik, 0),
         b=b, hq=hq, width=w, block_k=block_k, dtype=q.dtype,

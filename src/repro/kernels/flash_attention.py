@@ -180,5 +180,6 @@ def flash_mha(q, k, v, *, causal=True, window=None, q_positions=None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_mha",
     )(qlo, qhi, klo, khi, qpos[:, :, None], kpos[:, None, :], qt, kt, vt)
     return jnp.swapaxes(out[:, :, :sq], 1, 2)

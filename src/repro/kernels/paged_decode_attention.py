@@ -49,7 +49,8 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, *, cache_len,
     kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5),
                                block_size=bs, n_slots=m)
     out = decode_call(
-        kernel, grid=(b, m), num_scalar_prefetch=2,
+        kernel, name="paged_flash_decode", grid=(b, m),
+        num_scalar_prefetch=2,
         q_index=lambda bb, j, lens, tbl: (bb, 0, 0),
         kv_index=lambda bb, j, lens, tbl: (tbl[bb * m + j], 0, 0),
         b=b, hq=hq, width=w, block_k=bs, dtype=q.dtype, interpret=interpret,

@@ -22,6 +22,7 @@ from repro.configs.base import ModelConfig
 from repro.data import packing
 from repro.core import dfg as DFG
 from repro.core import fault as FLT
+from repro.core import tracing
 from repro.core.estimator import CostModel, Profile
 from repro.core.plan import Cluster, ExecutionPlan
 from repro.core.runtime import ModelState, RuntimeEngine
@@ -239,35 +240,62 @@ class RLHFExperiment:
                 raise ValueError(f"impl={tier!r} not in {OPS.IMPLS}")
         rng = jax.random.PRNGKey(exp.seed + 1)
 
-        gen_fn = jax.jit(lambda p, b, k: MDL.generate(
-            p, a_cfg, b, num_new_tokens=exp.gen_len, rng=k,
-            impl=rollout_impl, fused=exp.fused_sampling, eos_id=exp.eos_id,
-            sampler=exp.sampler, top_k=exp.top_k, top_p=exp.top_p))
-        ref_fn = jax.jit(lambda p, toks: PPO.sequence_logprobs(
-            p, a_cfg, toks, gen_start, impl=impl, remat=False))
-        rew_fn = jax.jit(lambda p, toks, m: RWD.score_sequences(
-            p, c_cfg, toks, m, impl=impl))
-        val_fn = jax.jit(lambda p, toks: PPO.sequence_values(
-            p, c_cfg, toks, gen_start, impl=impl, remat=False))
+        # named functions, not lambdas: the jitted program is named after
+        # the function (``jit_actor_generate``), which is how a device
+        # trace's module events say which call ran
+        def actor_generate(p, b, k):
+            return MDL.generate(
+                p, a_cfg, b, num_new_tokens=exp.gen_len, rng=k,
+                impl=rollout_impl, fused=exp.fused_sampling,
+                eos_id=exp.eos_id, sampler=exp.sampler, top_k=exp.top_k,
+                top_p=exp.top_p)
+
+        def ref_logprobs(p, toks):
+            return PPO.sequence_logprobs(p, a_cfg, toks, gen_start,
+                                         impl=impl, remat=False)
+
+        def reward_scores(p, toks, m):
+            return RWD.score_sequences(p, c_cfg, toks, m, impl=impl)
+
+        def critic_values(p, toks):
+            return PPO.sequence_values(p, c_cfg, toks, gen_start, impl=impl,
+                                       remat=False)
+
+        gen_fn, ref_fn, rew_fn, val_fn = map(
+            jax.jit, (actor_generate, ref_logprobs, reward_scores,
+                      critic_values))
         if exp.packed_training:
             # one static max_seqlen (the padded S) keys the banded varlen
             # reference; per-iteration token totals vary but are bucketed
             # by pack_minibatches, so recompiles stay bounded
-            actor_step = jax.jit(PPO.make_packed_actor_train_step(
+            a_step = PPO.make_packed_actor_train_step(
                 a_cfg, hp, exp.opt, impl=impl,
-                max_seqlen=exp.prompt_len + exp.gen_len),
-                donate_argnums=(0, 1))
-            critic_step = jax.jit(PPO.make_packed_critic_train_step(
+                max_seqlen=exp.prompt_len + exp.gen_len)
+            c_step = PPO.make_packed_critic_train_step(
                 c_cfg, hp, exp.opt, impl=impl,
-                max_seqlen=exp.prompt_len + exp.gen_len),
-                donate_argnums=(0, 1))
+                max_seqlen=exp.prompt_len + exp.gen_len)
         else:
-            actor_step = jax.jit(PPO.make_actor_train_step(
-                a_cfg, hp, exp.opt, gen_start, impl=impl),
-                donate_argnums=(0, 1))
-            critic_step = jax.jit(PPO.make_critic_train_step(
-                c_cfg, hp, exp.opt, gen_start, impl=impl),
-                donate_argnums=(0, 1))
+            a_step = PPO.make_actor_train_step(a_cfg, hp, exp.opt, gen_start,
+                                               impl=impl)
+            c_step = PPO.make_critic_train_step(c_cfg, hp, exp.opt,
+                                                gen_start, impl=impl)
+
+        def actor_train_step(params, opt_state, batch):
+            return a_step(params, opt_state, batch)
+
+        def critic_train_step(params, opt_state, batch):
+            return c_step(params, opt_state, batch)
+
+        actor_step = jax.jit(actor_train_step, donate_argnums=(0, 1))
+        critic_step = jax.jit(critic_train_step, donate_argnums=(0, 1))
+
+        def train(model, step, ms, batch):
+            """One train step and the host sync of its stats."""
+            with tracing.span("ppo.step", model=model):
+                ms.params, ms.opt_state, stats = step(ms.params,
+                                                      ms.opt_state, batch)
+            with tracing.span("ppo.sync", model=model):
+                return jax.tree.map(float, stats)
 
         state = {"rng": rng}
 
@@ -337,25 +365,25 @@ class RLHFExperiment:
 
         def actor_train(ms, inputs):
             mask = inputs["gen_mask"]
-            shaped = PPO.shaped_rewards(hp, inputs["rewards"], inputs["logp"],
-                                        inputs["ref_logp"], mask)
-            adv, _ = PPO.gae(hp, shaped, inputs["values"], mask)
+            with tracing.span("ppo.adv", model="actor"):
+                shaped = PPO.shaped_rewards(hp, inputs["rewards"],
+                                            inputs["logp"],
+                                            inputs["ref_logp"], mask)
+                adv, _ = PPO.gae(hp, shaped, inputs["values"], mask)
             batch = {"tokens": inputs["seq"], "logp": inputs["logp"],
                      "adv": adv, "mask": mask}
-            ms.params, ms.opt_state, stats = actor_step(ms.params,
-                                                        ms.opt_state, batch)
-            return {"actor_stats": jax.tree.map(float, stats)}
+            return {"actor_stats": train("actor", actor_step, ms, batch)}
 
         def critic_train(ms, inputs):
             mask = inputs["gen_mask"]
-            shaped = PPO.shaped_rewards(hp, inputs["rewards"], inputs["logp"],
-                                        inputs["ref_logp"], mask)
-            _, ret = PPO.gae(hp, shaped, inputs["values"], mask)
+            with tracing.span("ppo.adv", model="critic"):
+                shaped = PPO.shaped_rewards(hp, inputs["rewards"],
+                                            inputs["logp"],
+                                            inputs["ref_logp"], mask)
+                _, ret = PPO.gae(hp, shaped, inputs["values"], mask)
             batch = {"tokens": inputs["seq"], "values": inputs["values"][:, :-1],
                      "ret": ret, "mask": mask}
-            ms.params, ms.opt_state, stats = critic_step(ms.params,
-                                                         ms.opt_state, batch)
-            return {"critic_stats": jax.tree.map(float, stats)}
+            return {"critic_stats": train("critic", critic_step, ms, batch)}
 
         # ---------------------------------------------- packed train path
         P, G = exp.prompt_len, exp.gen_len
@@ -386,18 +414,18 @@ class RLHFExperiment:
             return lens, s, logp_full, mask_full, adv, ret
 
         def actor_train_packed(ms, inputs):
-            lens, s, logp_full, mask_full, adv, _ = _packed_prep(inputs)
+            with tracing.span("ppo.adv", model="actor"):
+                lens, s, logp_full, mask_full, adv, _ = _packed_prep(inputs)
             batch = packing.pack_minibatches(
                 inputs["seq"],
                 {"logp": logp_full, "adv": packing.unpack(adv, lens, s),
                  "mask": mask_full},
                 lens, hp.n_minibatches)
-            ms.params, ms.opt_state, stats = actor_step(ms.params,
-                                                        ms.opt_state, batch)
-            return {"actor_stats": jax.tree.map(float, stats)}
+            return {"actor_stats": train("actor", actor_step, ms, batch)}
 
         def critic_train_packed(ms, inputs):
-            lens, s, _, mask_full, _, ret = _packed_prep(inputs)
+            with tracing.span("ppo.adv", model="critic"):
+                lens, s, _, mask_full, _, ret = _packed_prep(inputs)
             old_full = jnp.zeros_like(mask_full).at[:, P:].set(
                 inputs["values"][:, :-1])
             batch = packing.pack_minibatches(
@@ -405,9 +433,7 @@ class RLHFExperiment:
                 {"values": old_full, "ret": packing.unpack(ret, lens, s),
                  "mask": mask_full},
                 lens, hp.n_minibatches)
-            ms.params, ms.opt_state, stats = critic_step(ms.params,
-                                                         ms.opt_state, batch)
-            return {"critic_stats": jax.tree.map(float, stats)}
+            return {"critic_stats": train("critic", critic_step, ms, batch)}
 
         if exp.packed_training:
             actor_train, critic_train = actor_train_packed, critic_train_packed
