@@ -1,17 +1,22 @@
 """Flash-decode: single-token attention over a (ring or linear) KV cache as a
 Pallas TPU kernel.
 
-One grid instance handles one batch row and all of its heads.  The cache
-keeps its model layout (B, C, Hkv, D), viewed as (B, C, Hkv*D) — a free
-reshape — so a KV tile is (block_k x Hkv*D): the sequence block on the
-sublane axis and every KV head together on the lane axis, which meets the
-(8, 128) tiling at any head_dim.  The query is spread block-diagonally to
-(Hq, Hkv*D) (query head h keeps its values in the lane slot of its KV head
-and zeros elsewhere), so one (Hq x Hkv*D) . (Hkv*D x block_k) product
-scores every head against its own KV head, and the PV product's diagonal
-slots are the per-head outputs.  ``cache_len`` arrives by scalar prefetch
-and masks unwritten slots — ring caches (window attention) are handled by
-the same bound since every resident slot is in-window by construction.
+One grid instance handles one batch row and all of its heads. The cache is
+read where it lies: the stack of a scan group's caches (L, B, C, Hkv*D),
+the layout the model keeps, with a ``layer`` index that arrives by scalar
+prefetch, so no per-layer slice, relayout or pad is materialised; or one
+layer (B, C, Hkv, D), viewed as a stack of one. A KV tile is (block_k x
+Hkv*D): the sequence block on the sublane axis and every KV head together
+on the lane axis, which meets the (8, 128) tiling at any head_dim;
+``block_k`` is picked from the capacity (``kv_tile``). The query is spread
+block-diagonally to (Hq, Hkv*D) (query head h keeps its values in the lane
+slot of its KV head and zeros elsewhere), so one (Hq x Hkv*D) . (Hkv*D x
+block_k) product scores every head against its own KV head, and the PV
+product's diagonal slots are the per-head outputs. ``cache_len`` arrives by
+scalar prefetch and masks unwritten slots -- ring caches (window attention)
+are handled by the same bound since every resident slot is in-window by
+construction. Tiles past the last valid key map to that key's tile, so they
+are neither fetched again nor computed: a step reads only the valid prefix.
 """
 
 from __future__ import annotations
@@ -73,18 +78,19 @@ def online_softmax_step(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def decode_call(kernel, *, name, grid, num_scalar_prefetch, q_index,
-                kv_index, b, hq, width, block_k, dtype, interpret):
+                kv_index, kv_block, b, hq, width, dtype, interpret):
     """The ``pallas_call`` shared by the contiguous and paged decode
-    kernels: a (b, kv-tile) grid over (1, Hq, W) queries/outputs and
-    (1, block_k, W) KV tiles, with (max, denom, acc) scratch.  ``name``
-    names the kernel in the compiled program and in device traces."""
+    kernels: a (b, kv-tile) grid over (1, Hq, W) queries/outputs and KV
+    tiles of ``kv_block`` that the kernel sees as (1, block_k, W), with
+    (max, denom, acc) scratch.  ``name`` names the kernel in the compiled
+    program and in device traces."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, hq, width), q_index),
-            pl.BlockSpec((1, block_k, width), kv_index),
-            pl.BlockSpec((1, block_k, width), kv_index),
+            pl.BlockSpec(kv_block, kv_index),
+            pl.BlockSpec(kv_block, kv_index),
         ],
         out_specs=pl.BlockSpec((1, hq, width), q_index),
         scratch_shapes=[
@@ -104,8 +110,19 @@ def decode_call(kernel, *, name, grid, num_scalar_prefetch, q_index,
     )
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, block_k, n_k, cap):
+def kv_tile(cap: int) -> int:
+    """Rows per KV tile: the largest of 512/256/128 that divides the cache
+    capacity (every production capacity is a multiple of 128), else the
+    largest multiple of 16 below 128 that does, else the whole capacity --
+    a tile always divides the cache, so it is never padded."""
+    for bk in (512, 256, 128, *range(112, 0, -16)):
+        if cap % bk == 0:
+            return bk
+    return cap
+
+
+def _kernel(len_ref, last_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+            l_scr, acc_scr, *, scale, block_k, n_k, cap):
     bb = pl.program_id(0)
     ik = pl.program_id(1)
     online_softmax_step(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
@@ -113,33 +130,36 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                         k_start=ik * block_k, step=ik, n_steps=n_k)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
-def flash_decode(q, k_cache, v_cache, *, cache_len, window=None, block_k=256,
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def flash_decode(q, k_cache, v_cache, *, cache_len, layer=None, window=None,
                  interpret=False):
-    """q: (B, Hq, D); caches: (B, C, Hkv, D); cache_len: (B,) int32.
-    Returns (B, Hq, D)."""
+    """q: (B, Hq, D); cache_len: (B,) int32.  Caches: a scan group's stack
+    (L, B, C, Hkv*D) with ``layer`` the scalar index of the layer to attend
+    over, or one layer (B, C, Hkv, D).  Returns (B, Hq, D)."""
     b, hq, d = q.shape
-    _, cap, hkv, _ = k_cache.shape
-    w = hkv * d
-    block_k = min(block_k, cap)
-    pad = (-cap) % block_k
-    kw = k_cache.reshape(b, cap, w)
-    vw = v_cache.reshape(b, cap, w)
-    if pad:  # non-aligned caches: pad (masked by ``limit``); production
-        # cache capacities are block-aligned so this is normally a no-op
-        kw = jnp.pad(kw, ((0, 0), (0, pad), (0, 0)))
-        vw = jnp.pad(vw, ((0, 0), (0, pad), (0, 0)))
-    n_k = (cap + pad) // block_k
+    if layer is None:
+        k_cache, v_cache = (c.reshape(1, *c.shape[:2], -1)
+                            for c in (k_cache, v_cache))
+        layer = 0
+    n_layers, _, cap, w = k_cache.shape
+    hkv = w // d
+    block_k = kv_tile(cap)
+    n_k = cap // block_k
     # ring caches (window attention): every resident slot is valid
     eff_cap = cap if window is None else min(cap, window)
+    lens = cache_len.astype(jnp.int32)
+    # the tile of each row's last valid key: later tiles map to it
+    last = (jnp.clip(lens, 1, eff_cap) - 1) // block_k
 
     kernel = functools.partial(_kernel, scale=1.0 / (d ** 0.5),
                                block_k=block_k, n_k=n_k, cap=eff_cap)
     out = decode_call(
-        kernel, name="flash_decode", grid=(b, n_k), num_scalar_prefetch=1,
-        q_index=lambda bb, ik, lens: (bb, 0, 0),
-        kv_index=lambda bb, ik, lens: (bb, ik, 0),
-        b=b, hq=hq, width=w, block_k=block_k, dtype=q.dtype,
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), spread_heads(q, hkv), kw, vw)
+        kernel, name="flash_decode", grid=(b, n_k), num_scalar_prefetch=3,
+        q_index=lambda bb, ik, lens, last, li: (bb, 0, 0),
+        kv_index=lambda bb, ik, lens, last, li: (
+            li[0], bb, jnp.minimum(ik, last[bb]), 0),
+        kv_block=(None, 1, block_k, w),
+        b=b, hq=hq, width=w, dtype=q.dtype, interpret=interpret,
+    )(lens, last, jnp.asarray(layer, jnp.int32).reshape(1),
+      spread_heads(q, hkv), k_cache, v_cache)
     return gather_heads(out, hkv)

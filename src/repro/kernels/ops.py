@@ -64,14 +64,23 @@ def varlen_mha(q, k, v, cu_seqlens, *, causal=True, window=None,
         interpret=(impl == "pallas_interpret"))
 
 
-def decode_mha(q, k_cache, v_cache, *, cache_len, window=None, impl="reference"):
+def decode_mha(q, k_cache, v_cache, *, cache_len, window=None, layer=None,
+               impl="reference"):
+    """Single-token attention over one layer's cache (B, C, Hkv, D), or over
+    layer ``layer`` of a scan group's stacked cache (L, B, C, Hkv*D), which
+    the kernel reads in place."""
     _check(impl)
     if impl == "reference":
+        if layer is not None:
+            k_cache, v_cache = (
+                jax.lax.dynamic_index_in_dim(c, layer, 0, False).reshape(
+                    *c.shape[1:3], -1, q.shape[-1])
+                for c in (k_cache, v_cache))
         return ref.decode_mha_ref(q, k_cache, v_cache, cache_len=cache_len,
                                   window=window)
     from repro.kernels import decode_attention
     return decode_attention.flash_decode(
-        q, k_cache, v_cache, cache_len=cache_len, window=window,
+        q, k_cache, v_cache, cache_len=cache_len, layer=layer, window=window,
         interpret=(impl == "pallas_interpret"))
 
 

@@ -53,7 +53,8 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, *, cache_len,
         num_scalar_prefetch=2,
         q_index=lambda bb, j, lens, tbl: (bb, 0, 0),
         kv_index=lambda bb, j, lens, tbl: (tbl[bb * m + j], 0, 0),
-        b=b, hq=hq, width=w, block_k=bs, dtype=q.dtype, interpret=interpret,
+        kv_block=(1, bs, w),
+        b=b, hq=hq, width=w, dtype=q.dtype, interpret=interpret,
     )(cache_len.astype(jnp.int32), block_table.astype(jnp.int32).reshape(-1),
       spread_heads(q, hkv), k_pool.reshape(n, bs, w),
       v_pool.reshape(n, bs, w))

@@ -53,26 +53,24 @@ def _batch_spec(bsz: int, mesh, ax):
 
 
 def _cache_specs_tree(cache_shapes, bspec, seq_shard: bool):
-    """KV caches: (n, B, S, H, Dh) — batch over data axes (or, for batch-1
+    """KV caches: (n, B, S, Hkv*Dh) — batch over data axes (or, for batch-1
     long-context cells, the sequence axis: sequence parallelism), innermost
-    dim over the TP axis when divisible."""
+    dim over the TP axis when divisible; recurrent states likewise."""
 
-    def spec(x):
+    def spec(path, x):
         parts = [None] * x.ndim
         if x.ndim >= 2 and x.shape[1] > 1:
             parts[1] = bspec
-        if x.ndim == 5:
-            if seq_shard and x.shape[1] == 1 and x.shape[2] % 16 == 0 \
+        if x.ndim >= 4:
+            if path[-1].key in ("k", "v") and seq_shard \
+                    and x.shape[1] == 1 and x.shape[2] % 16 == 0 \
                     and x.shape[2] >= 4096:
                 parts[2] = "data"
             if x.shape[-1] % 16 == 0:
                 parts[-1] = "model"
-        elif x.ndim == 4:  # (n, B, K, CH) conv states
-            if x.shape[-1] % 16 == 0:
-                parts[-1] = "model"
         return P(*parts)
 
-    return jax.tree.map(spec, cache_shapes)
+    return jax.tree_util.tree_map_with_path(spec, cache_shapes)
 
 
 @dataclasses.dataclass
@@ -231,16 +229,9 @@ def probe_costs(cell: CellSpec):
                 lambda: T.group_cache_init(cfg, specs, 1, bsz,
                                            shape.seq_len + 1,
                                            jnp.dtype(cfg.dtype)))
-            cache_one = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
-                cache_shapes)
-            cspec = _cache_specs_tree(
-                jax.tree.map(lambda s: jax.ShapeDtypeStruct((1,) + s.shape,
-                                                            s.dtype),
-                             cache_one), bspec,
-                seq_shard=(cell.shape == "long_500k"))
-            cspec = jax.tree.map(lambda p: P(*p[1:]), cspec,
-                                 is_leaf=lambda x: isinstance(x, P))
+            # one layer's stack (1, ...): the layout the decode scan carries
+            cspec = _cache_specs_tree(cache_shapes, bspec,
+                                      seq_shard=(cell.shape == "long_500k"))
             csh = jax.tree.map(ns, cspec)
 
             def dec_probe(xx, gp, cache):
@@ -248,12 +239,13 @@ def probe_costs(cell: CellSpec):
                     for i, s in enumerate(specs):
                         xx, cache[f"b{i}"] = T.block_decode(
                             gp[f"b{i}"], cfg, s, xx, cache[f"b{i}"],
-                            jnp.int32(shape.seq_len - 1), impl="reference")
+                            jnp.int32(shape.seq_len - 1), jnp.int32(0),
+                            impl="reference")
                     return xx, cache
 
             j = jax.jit(dec_probe,
                         in_shardings=(ns(P(bspec, None, None)), bsh, csh))
-            comp = j.lower(x, block_shapes, cache_one).compile()
+            comp = j.lower(x, block_shapes, cache_shapes).compile()
             ca = comp.cost_analysis()
             out.append({"trip": n,
                         "fwd": {"flops": ca.get("flops", 0.0),

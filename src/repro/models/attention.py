@@ -1,10 +1,13 @@
 """GQA attention layer (qk-norm, QKV-bias, RoPE, sliding window) + KV caches.
 
 The cache is a dict so the whole model state remains a plain pytree:
-  full   : k/v of shape (B, S_max, Hkv, Dh), linear writes at position t
-  window : k/v of shape (B, W, Hkv, Dh), ring-buffer writes at t % W
-RoPE is applied before caching, so ring-buffer slot order is irrelevant
-(attention is set-wise given positions are baked into k).
+  full   : k/v of shape (B, S_max, Hkv*Dh), linear writes at position t
+  window : k/v of shape (B, W, Hkv*Dh), ring-buffer writes at t % W
+The KV heads share the last axis, the layout the decode kernel reads, so a
+step neither relayouts nor copies the cache for it (with Hkv*Dh a multiple
+of 128 the tiled layout also carries no padding, where (Hkv, Dh) = (2, 64)
+would).  RoPE is applied before caching, so ring-buffer slot order is
+irrelevant (attention is set-wise given positions are baked into k).
 """
 
 from __future__ import annotations
@@ -104,19 +107,21 @@ def encode_cross_kv(p, cfg: ModelConfig, enc_out):
 
 def cache_init(cfg: ModelConfig, spec: LayerSpec, batch, max_len, dtype):
     cap = min(spec.window, max_len) if spec.window else max_len
-    shape = (batch, cap, cfg.n_kv_heads, cfg.head_dim)
+    shape = (batch, cap, cfg.kv_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def cache_spec(cfg: ModelConfig, spec: LayerSpec, batch, max_len, dtype):
     cap = min(spec.window, max_len) if spec.window else max_len
-    sh = jax.ShapeDtypeStruct((batch, cap, cfg.n_kv_heads, cfg.head_dim), dtype)
+    sh = jax.ShapeDtypeStruct((batch, cap, cfg.kv_dim), dtype)
     return {"k": sh, "v": sh}
 
 
 def prefill_into_cache(cache, spec: LayerSpec, k, v, seq_len: int):
-    """Write a full prefill's roped k/v into the cache (ring for window)."""
+    """Write a full prefill's roped k/v (B, S, Hkv, Dh) into the cache
+    (ring for window)."""
     cap = cache["k"].shape[1]
+    k, v = (a.reshape(*a.shape[:2], -1) for a in (k, v))
     if seq_len <= cap:
         # contiguous prefix: a static slice-update, not a gather/scatter
         return {
@@ -248,23 +253,26 @@ def ragged_attn_decode_apply(p, cfg: ModelConfig, spec: LayerSpec, x, cache,
     return y, new_cache
 
 
-def attn_decode_apply(p, cfg: ModelConfig, spec: LayerSpec, x, cache, t, *,
-                      impl="reference"):
-    """One-token decode.  x: (B, 1, D); t: scalar int32 position.
-    Returns (y, new_cache)."""
+def attn_decode_apply(p, cfg: ModelConfig, spec: LayerSpec, x, cache, t,
+                      layer, *, impl="reference"):
+    """One-token decode on layer ``layer`` of a scan group's stacked cache
+    {"k"/"v": (L, B, C, Hkv*Dh)}.  x: (B, 1, D); t: scalar int32 position.
+    The new token's k/v go in as one (1, B, 1, Hkv*Dh) update at
+    (layer, 0, slot), which XLA makes in place on the carried stack, and
+    the attention reads the layer where it lies.  Returns (y, new_cache)."""
     b = x.shape[0]
     positions = jnp.full((b, 1), t, dtype=jnp.int32)
     q, k, v = _project_qkv(p, cfg, x, x, positions, positions, use_rope=True)
-    cap = cache["k"].shape[1]
+    cap = cache["k"].shape[2]
     slot = (t % cap) if spec.window else t
     new_cache = {
-        "k": jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), slot, axis=1),
-        "v": jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), slot, axis=1),
-    }
+        name: jax.lax.dynamic_update_slice(
+            cache[name], new.reshape(1, b, 1, -1).astype(cache[name].dtype),
+            (layer, 0, slot, 0))
+        for name, new in (("k", k), ("v", v))}
     cache_len = jnp.full((b,), t + 1, dtype=jnp.int32)
     out = ops.decode_mha(q[:, 0], new_cache["k"], new_cache["v"],
-                         cache_len=cache_len, window=spec.window, impl=impl)
+                         cache_len=cache_len, window=spec.window, layer=layer,
+                         impl=impl)
     y = L.dense_apply(p["wo"], out.reshape(b, 1, cfg.q_dim).astype(x.dtype))
     return y, new_cache

@@ -141,8 +141,9 @@ def paged_insert(cfg: ModelConfig, caches, dense_caches, slots, table_rows,
 
     ``dense_caches`` comes from ``model.prefill(..., max_len=prompt_len)``
     on a (W, prompt_len) batch (leaves carry a leading scan axis then the
-    batch axis); ``slots``: (W,) server slot indices — out-of-range entries
-    (padding rows of a partially-filled admission batch) are dropped by the
+    batch axis; attention K/V keep their heads on one axis, Hkv*Dh);
+    ``slots``: (W,) server slot indices — out-of-range entries (padding
+    rows of a partially-filled admission batch) are dropped by the
     scatter; ``table_rows``: (W, nb) physical block ids covering each
     prompt, nb = ceil(prompt_len / block_size) (static) — padding rows
     point at the scratch block 0.  Jit-compatible: one program per
@@ -161,7 +162,8 @@ def paged_insert(cfg: ModelConfig, caches, dense_caches, slots, table_rows,
                 assert table_rows.shape == (w, nb), (table_rows.shape, w, nb)
                 pad = (-prompt_len) % bs
                 def put(pool, dk):
-                    x = dk[:, :, :prompt_len]  # (n, W, P, H, Dh)
+                    x = dk[:, :, :prompt_len].reshape(
+                        n, w, prompt_len, *pool.shape[3:])  # (n, W, P, H, Dh)
                     if pad:
                         x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0),
                                         (0, 0)))
@@ -173,11 +175,10 @@ def paged_insert(cfg: ModelConfig, caches, dense_caches, slots, table_rows,
             elif spec.kind == ATTN:
                 cap_d = d["k"].shape[2]  # min(window, prompt_len)
                 grp[f"b{i}"] = {
-                    "k": c["k"].at[:, slots, :cap_d].set(
-                        d["k"].astype(c["k"].dtype)),
-                    "v": c["v"].at[:, slots, :cap_d].set(
-                        d["v"].astype(c["v"].dtype)),
-                }
+                    name: c[name].at[:, slots, :cap_d].set(
+                        d[name].reshape(*d[name].shape[:3], *c[name].shape[3:])
+                        .astype(c[name].dtype))
+                    for name in ("k", "v")}
             else:  # recurrent state: copy rows
                 grp[f"b{i}"] = jax.tree.map(
                     lambda cc, dd: cc.at[:, slots].set(

@@ -104,21 +104,37 @@ def block_apply(p, cfg, spec, x, positions, *, causal=True, impl="reference",
     return x, aux, state
 
 
-def block_decode(p, cfg, spec, x, cache, t, *, impl="reference", cross=False):
-    """Single-token block step.  Returns (x, new_cache)."""
+def _layer_of(stack, layer):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), stack)
+
+
+def _set_layer(stack, new, layer):
+    return jax.tree.map(
+        lambda a, u: jax.lax.dynamic_update_index_in_dim(
+            a, u.astype(a.dtype), layer, 0), stack, new)
+
+
+def block_decode(p, cfg, spec, x, cache, t, layer, *, impl="reference",
+                 cross=False):
+    """Single-token block step on layer ``layer`` of a scan group's stacked
+    cache (every leaf (L, ...)).  Returns (x, new_cache): the stack with
+    this layer's entries updated in place -- one token of K/V for attention,
+    the small recurrent state for LRU/SSM; cross-attention K/V is read."""
     mixer_cache = cache["self"] if cross else cache
     h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     if spec.kind == ATTN:
         y, new_mixer = A.attn_decode_apply(p["mixer"], cfg, spec, h,
-                                           mixer_cache, t, impl=impl)
-    elif spec.kind == LRU:
-        y, new_mixer = R.lru_decode_apply(p["mixer"], cfg, h, mixer_cache)
+                                           mixer_cache, t, layer, impl=impl)
     else:
-        y, new_mixer = S.ssm_decode_apply(p["mixer"], cfg, h, mixer_cache)
+        step = R.lru_decode_apply if spec.kind == LRU else S.ssm_decode_apply
+        y, state = step(p["mixer"], cfg, h, _layer_of(mixer_cache, layer))
+        new_mixer = _set_layer(mixer_cache, state, layer)
     x = x + y
     if cross:
         hx = L.rmsnorm_apply(p["lnx"], x, cfg.norm_eps)
-        x = x + A.cross_attn_apply(p["xattn"], cfg, hx, enc_kv=cache["xkv"],
+        x = x + A.cross_attn_apply(p["xattn"], cfg, hx,
+                                   enc_kv=_layer_of(cache["xkv"], layer),
                                    impl=impl)
     x, _ = _ffn(p, cfg, x, impl=impl, want_aux=False)
     new_cache = {"self": new_mixer, "xkv": cache["xkv"]} if cross else new_mixer
@@ -326,18 +342,23 @@ def stack_paged_verify(groups_params, cfg: ModelConfig, x, caches,
 
 def stack_decode(groups_params, cfg: ModelConfig, x, caches, t, *,
                  impl="reference", cross=False):
-    """x: (B, 1, D); t: scalar position.  Returns (x, new_caches)."""
+    """x: (B, 1, D); t: scalar position.  Returns (x, new_caches).
+
+    The layer scan takes the params as xs and carries each group's stacked
+    caches with the layer index: every layer writes its token in place and
+    reads its cache where it lies, so no step copies a layer's cache out of
+    the stack or back into it."""
     new_caches = []
     for (specs, n), gp, gc in zip(groups_of(cfg), groups_params, caches):
-        def body(xc, inp, specs=specs):
+        def body(carry, layer_p, specs=specs):
+            xc, cache, layer = carry
             xc = ctx.constrain(xc, ctx.BATCH, None, None)
-            layer_p, cache = inp
-            out_cache = {}
+            cache = dict(cache)
             for i, spec in enumerate(specs):
-                xc, out_cache[f"b{i}"] = block_decode(
-                    layer_p[f"b{i}"], cfg, spec, xc, cache[f"b{i}"], t,
+                xc, cache[f"b{i}"] = block_decode(
+                    layer_p[f"b{i}"], cfg, spec, xc, cache[f"b{i}"], t, layer,
                     impl=impl, cross=cross)
-            return xc, out_cache
-        x, nc = jax.lax.scan(body, x, (gp, gc))
-        new_caches.append(nc)
+            return (xc, cache, layer + 1), None
+        (x, gc, _), _ = jax.lax.scan(body, (x, gc, jnp.int32(0)), gp)
+        new_caches.append(gc)
     return x, new_caches

@@ -71,24 +71,57 @@ def test_mha_chunked_exact():
 
 # ------------------------------------------------------------ flash decode
 
+def _decode_case(b, cap, hq, hkv, d, window, lens, stack=None, id=None):
+    """``stack``: (layers, layer) -- the cache is a scan group's stack
+    (L, B, C, Hkv*D) and the kernel reads layer ``layer`` in place."""
+    return pytest.param(b, cap, hq, hkv, d, window, lens, stack,
+                        id=id or f"{b}-{cap}-{hq}-{hkv}-{d}-{window}-"
+                                 f"{'_'.join(map(str, lens))}-L{stack[0]}")
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,cap,hq,hkv,d,window,lens", [
-    (2, 512, 4, 2, 64, None, [100, 512]),
-    (2, 128, 8, 1, 128, 128, [50, 4000]),
-    (1, 300, 6, 3, 32, None, [299]),
-    (3, 64, 2, 2, 64, 64, [64, 10, 1]),
+@pytest.mark.parametrize("b,cap,hq,hkv,d,window,lens,stack", [
+    _decode_case(2, 512, 4, 2, 64, None, [100, 512],
+                 id="2-512-4-2-64-None-lens0"),
+    _decode_case(2, 128, 8, 1, 128, 128, [50, 4000],
+                 id="2-128-8-1-128-128-lens1"),
+    _decode_case(1, 300, 6, 3, 32, None, [299], id="1-300-6-3-32-None-lens2"),
+    _decode_case(3, 64, 2, 2, 64, 64, [64, 10, 1], id="3-64-2-2-64-64-lens3"),
+    # stacked caches: 640 = 5 tiles of 128 (256 does not divide it), rows
+    # ending inside the last, partly filled tile and inside tile 1
+    _decode_case(2, 640, 14, 2, 64, None, [600, 130], stack=(3, 1)),
+    _decode_case(2, 1152, 16, 8, 128, None, [1025, 1], stack=(2, 1)),
+    _decode_case(3, 384, 4, 1, 128, None, [384, 129, 2], stack=(4, 3)),
+    # a window ring: every slot valid once it has wrapped
+    _decode_case(2, 256, 4, 2, 64, 256, [1000, 200], stack=(2, 0)),
 ])
-def test_flash_decode_matches_ref(b, cap, hq, hkv, d, window, lens, dtype):
+def test_flash_decode_matches_ref(b, cap, hq, hkv, d, window, lens, stack,
+                                  dtype):
     ks = jax.random.split(RNG, 3)
     q = _rand(ks[0], (b, hq, d), dtype)
-    k = _rand(ks[1], (b, cap, hkv, d), dtype)
-    v = _rand(ks[2], (b, cap, hkv, d), dtype)
+    n, layer = stack or (1, 0)
+    k = _rand(ks[1], (n, b, cap, hkv, d), dtype)
+    v = _rand(ks[2], (n, b, cap, hkv, d), dtype)
     cl = jnp.array(lens, jnp.int32)
-    out = flash_decode(q, k, v, cache_len=cl, window=window, interpret=True)
-    want = ref.decode_mha_ref(q, k, v, cache_len=cl, window=window)
+    if stack is None:
+        out = flash_decode(q, k[0], v[0], cache_len=cl, window=window,
+                           interpret=True)
+    else:
+        out = flash_decode(q, k.reshape(n, b, cap, -1),
+                           v.reshape(n, b, cap, -1), cache_len=cl,
+                           layer=jnp.int32(layer), window=window,
+                           interpret=True)
+    want = ref.decode_mha_ref(q, k[layer], v[layer], cache_len=cl,
+                              window=window)
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol, rtol=tol)
+
+
+def test_kv_tile_divides_the_capacity():
+    from repro.kernels.decode_attention import kv_tile
+    assert [kv_tile(c) for c in (640, 1152, 1024, 384, 300, 48, 12)] == [
+        128, 128, 512, 128, 300, 48, 12]
 
 
 # ----------------------------------------------------------------- ssd

@@ -52,8 +52,14 @@ def test_train_step_no_nans(arch, reduced):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
-def test_decode_matches_forward(arch, reduced):
+@pytest.mark.parametrize("arch,impl", [
+    pytest.param(a, "reference", id=a) for a in ASSIGNED] + [
+    # the Pallas tier (interpreted) on each family stack_decode serves:
+    # dense, window ring + global, hybrid LRU, SSM, encoder-decoder
+    pytest.param(a, "pallas_interpret", id=f"{a}-pallas_interpret")
+    for a in ("qwen2-0.5b", "gemma3-1b", "recurrentgemma-9b", "mamba2-1.3b",
+              "seamless-m4t-medium")])
+def test_decode_matches_forward(arch, impl, reduced):
     """Stepwise decode from a mid-sequence prefill reproduces the
     full-sequence forward logits.
 
@@ -73,12 +79,12 @@ def test_decode_matches_forward(arch, reduced):
     full_logits = logits_of(p, cfg, h)
     cut = S - 4
     pb = {k: (v[:, :cut] if k == "tokens" else v) for k, v in batch.items()}
-    last_h, caches = prefill(p, cfg, pb, max_len=S)
+    last_h, caches = prefill(p, cfg, pb, max_len=S, impl=impl)
     lg = logits_of(p, cfg, last_h[:, None])[:, 0]
     errs = [float(jnp.max(jnp.abs(lg - full_logits[:, cut - 1])))]
     for t in range(cut, S - 1):
         lg, caches = decode_step(p, cfg, batch["tokens"][:, t], caches,
-                                 jnp.int32(t))
+                                 jnp.int32(t), impl=impl)
         errs.append(float(jnp.max(jnp.abs(lg - full_logits[:, t]))))
     assert max(errs) < 5e-4, errs
 

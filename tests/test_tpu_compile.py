@@ -4,14 +4,18 @@ Interpret mode does not check the chip's (8, 128) block tiling, SMEM/VMEM
 placement or Mosaic's lowering rules; these tests run the TPU compiler
 against a described (not attached) ``v5e:2x2`` topology at qwen2-0.5b
 widths (14 query heads, 2 KV heads, head_dim 64, bf16).  Nothing runs, so
-they say nothing about results or speed.
+they say nothing about results or speed -- except what the compiled program
+holds: the decode loop of ``generate`` is read for ops that move the KV
+cache.
 
 The topology is described inside a fixture, never at import, and the
 persistent compilation cache is off around the compiles (an entry written
 for a described chip cannot be read back without one).
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,3 +98,117 @@ def test_flash_mha_varlen_compiles(one_chip):
     _compile(lambda q, k, v, cu: flash_mha_varlen(q, k, v, cu), one_chip,
              ((t, HQ, D), BF16), ((t, HKV, D), BF16), ((t, HKV, D), BF16),
              ((B + 1,), jnp.int32))
+
+
+# ------------------------------------------------ the compiled decode loop
+
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+# ops that yield a new buffer from an existing one
+_MOVES = ("copy", "copy-start", "pad", "dynamic-slice", "slice-start",
+          "transpose")
+
+
+def _computations(hlo):
+    """{computation: {instruction: (type, opcode, rest of line)}}."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), {})
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            m = re.match(
+                r"^\s*(?:ROOT )?%(\S+) = (.+?) ([a-z][a-z0-9-]*)\((.*)$", line)
+            if m:
+                cur[m.group(1)] = m.group(2, 3, 4)
+    return comps
+
+
+def _arrays(typ):
+    """[(bytes, dims)] of each array in an HLO type."""
+    out = []
+    typ = re.sub(r"\{[^}]*\}", "", typ)  # layouts
+    for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", typ):
+        dims = tuple(int(x) for x in dims.split(",") if x)
+        n = _BYTES.get(dt, 4)
+        for x in dims:
+            n *= x
+        out.append((n, dims))
+    return out
+
+
+def _called(rest, key="calls|body|condition|to_apply|called_computations"):
+    return re.findall(rf"(?:{key})=\{{?%([\w.\-]+)", rest)
+
+
+def _decode_loop_bodies(comps):
+    """Bodies of the while loops that reach ``flash_decode``, with the
+    computations their fusions call."""
+    def reach(c, seen):
+        if c not in seen and c in comps:
+            seen.add(c)
+            for _, _, rest in comps[c].values():
+                for cc in _called(rest):
+                    reach(cc, seen)
+        return seen
+    bodies = set()
+    for ins in comps.values():
+        for _, op, rest in ins.values():
+            if op == "while":
+                body = _called(rest, "body")[0]
+                inner = reach(body, set())
+                if any(n.startswith("flash_decode") for c in inner
+                       for n in comps[c]):
+                    bodies |= inner
+    return bodies
+
+
+@pytest.mark.parametrize("arch,layers,prompt,gen", [
+    ("qwen2-0.5b", 3, 128, 512),   # (2, 64) heads, capacity 640 = 5 x 128
+    ("qwen3-1.7b", 2, 128, 1024),  # (8, 128) heads, qk-norm, capacity 1152
+])
+def test_decode_loop_moves_no_cache(one_chip, arch, layers, prompt, gen):
+    """``generate(fused=True, impl="pallas")`` at the benchmark's attention
+    widths and batch: inside the decode loop no copy, pad or slice yields a
+    buffer of one layer's KV cache or more, and every update of a cache
+    writes one token.  A capacity that 256 does not divide would catch a
+    pad to the kernel's tile."""
+    from repro.configs import ARCHS
+    from repro.models import model as MDL
+    cfg = dataclasses.replace(ARCHS[arch], num_layers=layers,
+                              n_superblocks=layers, vocab_size=4096)
+    cap = prompt + gen
+    layer_bytes = B * cap * cfg.kv_dim * 2
+    token_bytes = B * cfg.kv_dim * 2
+    on_chip = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: MDL.init_params(k, cfg), jax.random.PRNGKey(0)))
+    batch = {"tokens": on_chip(jax.ShapeDtypeStruct((B, prompt), jnp.int32))}
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    hlo = jax.jit(lambda p, b, k: MDL.generate(
+        p, cfg, b, num_new_tokens=gen, rng=k, impl="pallas", fused=True)
+    ).lower(params, batch, key).compile().as_text()
+
+    comps = _computations(hlo)
+    bodies = _decode_loop_bodies(comps)
+    assert bodies, "no while loop reaches flash_decode"
+    moves, updates = [], []
+    for c in bodies:
+        ins = comps[c]
+        for name, (typ, op, rest) in ins.items():
+            operands = [ins[o][0] for o in re.findall(r"%([\w.\-]+)",
+                                                       rest.split("),")[0])
+                        if o in ins]
+            shapes = [a for t in [typ] + operands for a in _arrays(t)]
+            touches_cache = any(cap in dims for _, dims in shapes)
+            if op in _MOVES and touches_cache and max(
+                    n for n, _ in _arrays(typ)) >= layer_bytes:
+                moves.append(f"{name} {op} {typ[:80]}")
+            if op == "dynamic-update-slice" and touches_cache:
+                update = sum(n for n, _ in _arrays(operands[1]))
+                if update > token_bytes:
+                    updates.append(f"{name}: {update} bytes")
+    assert not moves, moves
+    assert not updates, updates
