@@ -44,9 +44,9 @@ def altered_token_gap(cell, wkey, prog, seed: int) -> float:
     col = cell.prompt_len + int(rng.integers(cell.gen_len))
     seq[row, col] = (seq[row, col] + 1 + int(rng.integers(100))) % \
         cell.arch.vocab_size
-    ref = Reference(cell.arch, cell.hp, cell.prompt_len)
+    ref = Reference(cell.family, cell.arch, cell.hp, cell.prompt_len)
     with jax.default_matmul_precision("highest"):
-        lm = _f32(W.make(wkey, cell.arch)["lm"])
+        lm = _f32(W.make(wkey, cell.family, cell.arch)["lm"])
         lp = np.asarray(ref.logprobs(lm, jnp.asarray(seq)))
     return float(np.max(np.abs(it["logp"] - lp)))
 

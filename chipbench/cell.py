@@ -3,7 +3,8 @@ of the iterations the reference follows.
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``: a configuration
 file ``configs/<config>.json`` and a traffic file ``traffic/<mix>.json``,
-found by name.  ``limits/<cell>.json`` holds its correctness limits.
+found by name.  ``limits/<cell>.json`` holds its correctness limits.  The
+configuration's ``family`` names its architecture's module in ``archs/``.
 """
 
 from __future__ import annotations
@@ -17,8 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import weights as W
-from chipbench.arch import Arch
+from chipbench import archs
 from chipbench.reference import PPO
 
 HERE = Path(__file__).resolve().parent
@@ -41,8 +41,13 @@ class Cell:
     per_layer: list
 
     @property
-    def arch(self) -> Arch:
-        return Arch.from_file(self.config)
+    def family(self):
+        """The architecture's module (``chipbench/archs``)."""
+        return family_of(self.config)
+
+    @property
+    def arch(self):
+        return self.family.Arch.from_file(self.config)
 
     @property
     def batch(self) -> int:
@@ -61,6 +66,13 @@ class Cell:
         return self.batch * (self.prompt_len + self.gen_len)
 
     @property
+    def costs(self) -> dict:
+        """FLOPs and bytes of each call of one PPO iteration."""
+        return self.family.calls(self.arch, self.batch, self.prompt_len,
+                                 self.gen_len,
+                                 self.traffic["ppo"]["n_minibatches"])
+
+    @property
     def hp(self) -> PPO:
         t = self.traffic
         return PPO(n_minibatches=t["ppo"]["n_minibatches"],
@@ -71,6 +83,11 @@ class Cell:
                                                  "state_dtype")})
 
 
+def family_of(config: dict):
+    """The module of the architecture family the configuration names."""
+    return archs.load(config.get("family"))
+
+
 def load_cell(name: str, bench_path: Path = REPO / "BENCHMARK.json") -> Cell:
     bench = _load(bench_path)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -78,34 +95,38 @@ def load_cell(name: str, bench_path: Path = REPO / "BENCHMARK.json") -> Cell:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     limits_path = HERE / "limits" / f"{name}.json"
-    return Cell(name=name, chips=w["chips"],
+    cell = Cell(name=name, chips=w["chips"],
                 config=_load(HERE / "configs" / f"{w['config']}.json"),
                 traffic=_load(HERE / "traffic" / f"{w['traffic']}.json"),
                 limits=_load(limits_path) if limits_path.exists() else None,
                 end_to_end=bench["end_to_end"], per_layer=bench["per_layer"])
+    family_of(cell.config)  # a missing or unknown family fails here
+    return cell
 
 
 # ------------------------------------------------------------- the program
 
 def model_config(config: dict):
     """The program's ``ModelConfig``: the registered architecture with the
-    file's overrides, checked against the sizes the file states."""
+    file's overrides, checked against what the file states (the family's
+    ``stated``), and its parameter layout against the family's."""
     from repro.configs import ARCHS
+    from repro.models import model as MDL
     cfg = dataclasses.replace(ARCHS[config["arch"]], **config["overrides"])
-    a = Arch.from_file(config)
-    stated = {"d_model": a.hidden_size, "d_ff": a.intermediate_size,
-              "num_layers": a.num_hidden_layers,
-              "n_heads": a.num_attention_heads,
-              "n_kv_heads": a.num_key_value_heads, "head_dim": a.head_dim,
-              "vocab_size": a.vocab_size, "rope_theta": a.rope_theta,
-              "norm_eps": a.rms_norm_eps, "qkv_bias": a.qkv_bias,
-              "qk_norm": a.qk_norm, "dtype": a.dtype, "tie_embeddings": True,
-              "family": "dense", "ffn_kind": "gated", "act": "silu"}
-    wrong = {k: (getattr(cfg, k), v) for k, v in stated.items()
+    family = family_of(config)
+    a = family.Arch.from_file(config)
+    wrong = {k: (getattr(cfg, k), v) for k, v in family.stated(a).items()
              if getattr(cfg, k) != v}
     if wrong:
         raise SystemExit(f"{config['name']}: program config differs from "
                          f"the file (program, file): {wrong}")
+    for head in ("lm", "value"):
+        want = jax.eval_shape(lambda: MDL.init_params(  # noqa: B023
+            jax.random.PRNGKey(0), cfg, head=head))
+        if want != family.layout(a, head):
+            raise SystemExit(f"{config['name']}: the program's {head} "
+                             "parameter layout differs from the family's "
+                             "layout")
     return cfg
 
 
@@ -119,19 +140,12 @@ def build(cell: Cell, seed: int, weights: dict):
     this cell's configuration and traffic, on the benchmark's weights."""
     from repro.core.plan import Cluster
     from repro.core.runtime import ModelState
-    from repro.models import model as MDL
     from repro.optim import adamw
     from repro.rlhf.experiment import ExperimentConfig, RLHFExperiment
     from repro.rlhf.ppo import PPOHyperparameters
 
     cfg = model_config(cell.config)
     arch, t = cell.arch, cell.traffic
-    for head in ("lm", "value"):
-        want = jax.eval_shape(lambda: MDL.init_params(  # noqa: B023
-            jax.random.PRNGKey(0), cfg, head=head))
-        if want != W.layout(arch, head):
-            raise SystemExit(f"{cell.name}: the program's {head} parameter "
-                             "layout differs from chipbench/weights.layout")
     if t["ppo"].get("entropy_coef", 0.0) != 0.0:
         raise SystemExit("the reference has no entropy bonus")
     exp_cfg = ExperimentConfig(

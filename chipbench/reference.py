@@ -3,7 +3,9 @@
 It reads the benchmark's weights (``weights.py``) and the tokens the program
 served, and follows the program's first iterations: reference and reward
 scores, the actor's logprobs, the critic's values, then the minibatched PPO
-updates of actor and critic under AdamW.  Every computation is in float32
+updates of actor and critic under AdamW.  The model itself (its forward
+pass and LM head) is the architecture family's (``chipbench/archs``); the
+PPO and AdamW arithmetic here is shared.  Every computation is in float32
 and every matrix product runs at ``Precision.HIGHEST``.  What is stored
 follows the configuration: parameters in their served type (bfloat16, the
 value head float32), AdamW's moments in the traffic's ``state_dtype`` over
@@ -23,8 +25,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from chipbench.arch import Arch
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0  # largest finite float8_e4m3fn
@@ -65,77 +65,26 @@ class PPO:
 
 # ------------------------------------------------------------------ model
 
-def _rms(x, scale, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
-
-
-def _rope(x, theta):
-    """Rotate-half RoPE over positions 0..S-1; x (B, S, H, D)."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def forward(p, arch: Arch, tokens, dot):
-    """Final-norm hidden states (B, S, D) in float32."""
-    b, s = tokens.shape
-    h, hkv, hd = (arch.num_attention_heads, arch.num_key_value_heads,
-                  arch.head_dim)
-    eps = arch.rms_norm_eps
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    x = p["embed"]["table"][tokens]
-
-    def proj(y, w):
-        out = dot("bsd,df->bsf", y, w["w"])
-        return out + w["b"] if "b" in w else out
-
-    def layer(x, lp):
-        m = lp["mixer"]
-        y = _rms(x, lp["ln1"]["scale"], eps)
-        q = proj(y, m["wq"]).reshape(b, s, h, hd)
-        k = proj(y, m["wk"]).reshape(b, s, hkv, hd)
-        v = proj(y, m["wv"]).reshape(b, s, hkv, hd)
-        if "q_norm" in m:
-            q = _rms(q, m["q_norm"]["scale"], eps)
-            k = _rms(k, m["k_norm"]["scale"], eps)
-        q, k = _rope(q, arch.rope_theta), _rope(k, arch.rope_theta)
-        q = q.reshape(b, s, hkv, h // hkv, hd)
-        sc = dot("bqkgd,bskd->bkgqs", q, k) * hd ** -0.5
-        sc = jnp.where(causal, sc, -jnp.inf)
-        att = dot("bkgqs,bskd->bqkgd", jax.nn.softmax(sc, axis=-1), v)
-        x = x + dot("bsq,qd->bsd", att.reshape(b, s, h * hd), m["wo"]["w"])
-        y = _rms(x, lp["ln2"]["scale"], eps)
-        f = lp["ffn"]
-        g = jax.nn.silu(proj(y, f["w_gate"])) * proj(y, f["w_in"])
-        return x + proj(g, f["w_out"]), None
-
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, p["groups"][0]["b0"])
-    return _rms(x, p["final_norm"]["scale"], eps)
-
-
-def logprobs(p, arch, tokens, gen_start, dot):
+def logprobs(family, p, arch, tokens, gen_start, dot):
     """Logprob of each generated token (B, S - gen_start); one row of
     vocabulary logits at a time."""
-    hid = forward(p, arch, tokens, dot)[:, gen_start - 1:-1]
+    hid = family.forward(p, arch, tokens, dot)[:, gen_start - 1:-1]
     tgt = tokens[:, gen_start:]
+    head = family.lm_head(p)
 
     @jax.checkpoint
     def row(args):
         h, t = args
-        lg = dot("td,vd->tv", h, p["embed"]["table"])
+        lg = dot("td,vd->tv", h, head)
         lp = jax.nn.log_softmax(lg, axis=-1)
         return jnp.take_along_axis(lp, t[:, None], axis=-1)[:, 0]
 
     return jax.lax.map(row, (hid, tgt))
 
 
-def values(p, arch, tokens, gen_start, dot):
+def values(family, p, arch, tokens, gen_start, dot):
     """Values at positions gen_start-1 .. S-1 (B, T+1)."""
-    hid = forward(p, arch, tokens, dot)[:, gen_start - 1:]
+    hid = family.forward(p, arch, tokens, dot)[:, gen_start - 1:]
     return dot("btd,do->bto", hid, p["value_head"]["w"])[..., 0]
 
 
@@ -196,27 +145,30 @@ def _rows(fault, *xs):
 
 
 class Reference:
-    """Jitted reference programs for one configuration and one precision."""
+    """Jitted reference programs for one configuration and one precision;
+    ``family`` is the architecture's module (``chipbench/archs``)."""
 
-    def __init__(self, arch: Arch, hp: PPO, gen_start: int, dot="fp32",
+    def __init__(self, family, arch, hp: PPO, gen_start: int, dot="fp32",
                  fault=None):
         self.arch, self.hp, self.gen_start = arch, hp, gen_start
         d = make_dot(dot)
         g = gen_start
-        self.logprobs = jax.jit(lambda p, t: logprobs(_f32(p), arch, t, g, d))
-        self.values = jax.jit(lambda p, t: values(_f32(p), arch, t, g, d))
+        self.logprobs = jax.jit(
+            lambda p, t: logprobs(family, _f32(p), arch, t, g, d))
+        self.values = jax.jit(
+            lambda p, t: values(family, _f32(p), arch, t, g, d))
         self.advantages = jax.jit(functools.partial(advantages, hp))
 
         def actor_loss(p, tok, old, adv):
             tok, old, adv = _rows(fault, tok, old, adv)
-            new = logprobs(p, arch, tok, g, d)
+            new = logprobs(family, p, arch, tok, g, d)
             ratio = jnp.exp(jnp.clip(new - old, -20.0, 20.0))
             clipped = jnp.clip(ratio, 1 - hp.clip_eps, 1 + hp.clip_eps)
             return -jnp.mean(jnp.minimum(ratio * adv, clipped * adv))
 
         def critic_loss(p, tok, old, ret):
             tok, old, ret = _rows(fault, tok, old, ret)
-            new = values(p, arch, tok, g, d)[:, :-1]
+            new = values(family, p, arch, tok, g, d)[:, :-1]
             clipped = old + jnp.clip(new - old, -hp.value_clip, hp.value_clip)
             return 0.5 * jnp.mean(jnp.maximum(jnp.square(new - ret),
                                               jnp.square(clipped - ret)))

@@ -47,7 +47,7 @@ if str(REPO / "src") not in sys.path:
 import jax  # noqa: E402
 
 from chipbench import cell as C  # noqa: E402
-from chipbench import check, flops  # noqa: E402
+from chipbench import check  # noqa: E402
 from chipbench import trace as TR  # noqa: E402
 from chipbench import weights as W  # noqa: E402
 from chipbench.reference import Reference, follow  # noqa: E402
@@ -156,7 +156,7 @@ def warm_up(cell, seed: int, compiles: CompileCounter, settle: bool = True):
     key = W.seed_key(seed)
     wkey, pkey = jax.random.fold_in(key, 1), jax.random.fold_in(key, 2)
     t = time.perf_counter()
-    run = C.build(cell, seed, W.make(wkey, cell.arch))
+    run = C.build(cell, seed, W.make(wkey, cell.family, cell.arch))
     log(f"build (weights, plan search, executors) "
         f"{time.perf_counter() - t:.3f}s")
     follow_n = FOLLOW_ITERATIONS
@@ -199,18 +199,19 @@ def follow_reference(cell, wkey, seqs, limit_bytes: int, dot: str = "fp32",
     model's AdamW moments go to the host where both would not fit.
     ``programs`` keeps the jitted reference across calls in one process."""
     t = time.perf_counter()
-    arch = cell.arch
+    family, arch = cell.family, cell.arch
     programs = {} if programs is None else programs
     key = (cell.name, dot, fault)
     if key not in programs:
-        programs[key] = Reference(arch, cell.hp, cell.prompt_len, dot=dot,
-                                  fault=fault)
+        programs[key] = Reference(family, arch, cell.hp, cell.prompt_len,
+                                  dot=dot, fault=fault)
     ref = programs[key]
     # two models' bf16 weights, fp32 master and bf16 moments, and room for
     # the gradients and activations of a train step
     need = 20 * arch.param_count("lm") + 8e9
     offload = bool(limit_bytes) and need > limit_bytes
-    out = follow(ref, lambda: W.make(wkey, arch), seqs, offload=offload)
+    out = follow(ref, lambda: W.make(wkey, family, arch), seqs,
+                 offload=offload)
     log(f"reference ({dot}, fault {fault}) over {len(seqs)} iterations "
         f"{time.perf_counter() - t:.3f}s (idle moments "
         f"{'on the host' if offload else 'on the chip'})")
@@ -220,7 +221,6 @@ def follow_reference(cell, wkey, seqs, limit_bytes: int, dot: str = "fp32",
 def run_cell(cell, seed: int, seconds: float, traced: bool, devs: list,
              peak: dict, dump_trace: str | None = None) -> dict:
     """One run of a cell; returns the result line's object."""
-    arch = cell.arch
     compiles = CompileCounter()
     run, prog, wkey, pkey, i = warm_up(cell, seed, compiles)
     setup_s = time.perf_counter() - T_START
@@ -274,8 +274,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, devs: list,
     if traced:
         ctx = types.SimpleNamespace(
             trace=summary, iterations=n, chips=cell.chips, peak=peak,
-            costs=flops.calls(arch, cell.batch, cell.prompt_len, cell.gen_len,
-                              cell.traffic["ppo"]["n_minibatches"]))
+            costs=cell.costs)
         metrics = {}
         for m in cell.per_layer:
             value = load_metric(m["name"]).read(ctx)

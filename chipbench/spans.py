@@ -47,7 +47,6 @@ import jax
 from jax.profiler import ProfileData
 
 from chipbench import cell as C
-from chipbench import flops
 from chipbench import run as R
 from chipbench import trace as TR
 
@@ -277,9 +276,7 @@ def main(argv=None):
     summary = TR.reduce(events)
     ctx = types.SimpleNamespace(
         trace=summary, iterations=R.TRACE_ITERATIONS, chips=cell.chips,
-        peak=peak, costs=flops.calls(cell.arch, cell.batch, cell.prompt_len,
-                                     cell.gen_len,
-                                     cell.traffic["ppo"]["n_minibatches"]))
+        peak=peak, costs=cell.costs)
     existing = {m["name"]: R.load_metric(m["name"]).read(ctx)
                 for m in cell.per_layer}
     print(json.dumps({

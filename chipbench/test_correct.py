@@ -33,7 +33,8 @@ def tiny_cell() -> C.Cell:
         "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 512, "rope_theta": 1e6, "rms_norm_eps": 1e-6,
-        "torch_dtype": "bfloat16", "qkv_bias": True, "qk_norm": False}
+        "torch_dtype": "bfloat16", "qkv_bias": True, "qk_norm": False,
+        "family": "dense"}
     traffic = dict(full.traffic, prompt_len=8, gen_len=8, search_iters=5,
                    rollout_impl="reference")
     return C.Cell("tiny", 1, config, traffic, full.limits, full.end_to_end,

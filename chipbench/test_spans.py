@@ -14,7 +14,6 @@ from pathlib import Path
 import pytest
 
 from chipbench import cell as C
-from chipbench import flops
 from chipbench import run as R
 from chipbench import spans as S
 from chipbench import trace as TR
@@ -169,8 +168,7 @@ def test_existing_metrics_read_as_before():
     ctx = types.SimpleNamespace(
         trace=TR.reduce(TR.Events.load(BENCH_RECORDED)), iterations=1,
         chips=1, peak=peak,
-        costs=flops.calls(cell.arch, cell.batch, cell.prompt_len,
-                          cell.gen_len, cell.traffic["ppo"]["n_minibatches"]))
+        costs=cell.costs)
     got = {m["name"]: R.load_metric(m["name"]).read(ctx)
            for m in cell.per_layer}
     assert got == pytest.approx(PINNED, rel=1e-12)
