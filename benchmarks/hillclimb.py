@@ -66,8 +66,8 @@ def _flash_adjust(cell_key: str, arch: str, shape_name: str, res: dict):
         def _fwd(xx, gp):
             pos = jnp.arange(sl)[None, :]
             for i, s in enumerate(specs):
-                xx, _, _ = T.block_apply(gp[f"b{i}"], cfg, s, xx, pos,
-                                         impl=impl)
+                xx, *_ = T.block_apply(gp[f"b{i}"], cfg, s, xx, pos,
+                                       impl=impl)
             return xx
         return probe
 

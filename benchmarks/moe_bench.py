@@ -39,13 +39,13 @@ def bench_moe(arch="granite-moe-1b-a400m", batch=8, n_experts=16, top_k=2,
         cfg = dataclasses.replace(base, moe_dispatch=mode)
         params = init_params(key, cfg)
         pb = synth_batch(jax.random.PRNGKey(1), cfg, prompt, batch, "prefill")
-        last_h, caches = jax.jit(
+        last_h, caches, _ = jax.jit(
             lambda p, b: prefill(p, cfg, b, max_len=prompt + steps))(params, pb)
 
         def decode_n(p, tok, caches, cfg=cfg):
             def body(carry, t):
                 tok, caches = carry
-                lg, caches = decode_step(p, cfg, tok, caches, t)
+                lg, caches, _ = decode_step(p, cfg, tok, caches, t)
                 return (jnp.argmax(lg, -1).astype(jnp.int32), caches), None
             (tok, _), _ = jax.lax.scan(
                 body, (tok, caches), prompt + jnp.arange(steps, dtype=jnp.int32))

@@ -204,7 +204,8 @@ def four_chip_phase(cfg, devices, *, batch=8, seq=256, lr=1e-5):
                                 jnp.int32)
     # old logprobs = the current policy's, so the first PPO ratio is 1 and
     # no token is clipped out of the gradient
-    logp = jax.jit(lambda p, t: PPO.sequence_logprobs(p, cfg, t, gen_start))(
+    logp, _ = jax.jit(lambda p, t: PPO.sequence_logprobs(p, cfg, t,
+                                                         gen_start))(
         params, tokens)
     data = {"tokens": tokens, "logp": logp,
             "adv": jax.random.normal(ks[2], (batch, g)),

@@ -51,12 +51,12 @@ def main():
     print("mean logprob:", float(out["logprobs"].mean()))
 
     # interactive-style serving: explicit prefill + stepwise decode
-    last_h, caches = prefill(params, cfg, batch,
+    last_h, caches, _ = prefill(params, cfg, batch,
                              max_len=args.prompt_len + args.new)
     lg = logits_of(params, cfg, last_h[:, None])[:, 0]
     tok = jnp.argmax(lg, -1).astype(jnp.int32)
     for t in range(args.prompt_len, args.prompt_len + 4):
-        lg, caches = decode_step(params, cfg, tok, caches, jnp.int32(t))
+        lg, caches, _ = decode_step(params, cfg, tok, caches, jnp.int32(t))
         tok = jnp.argmax(lg, -1).astype(jnp.int32)
     print("stepwise decode OK; final greedy ids:", tok.tolist())
 

@@ -9,14 +9,14 @@ from repro.configs.llama import LLAMA_7B, LLAMA_13B, LLAMA_34B, LLAMA_70B, PAPER
 
 
 def _load():
-    from repro.configs import (arctic_480b, gemma3_1b, granite_moe_1b,
-                               internvl2_76b, llama, mamba2_13b, qwen2_05b,
-                               qwen3_17b, qwen25_14b, recurrentgemma_9b,
-                               seamless_m4t_medium)
+    from repro.configs import (arctic_480b, deepseek_v2_lite, gemma3_1b,
+                               granite_moe_1b, internvl2_76b, llama,
+                               mamba2_13b, qwen2_05b, qwen3_17b, qwen25_14b,
+                               recurrentgemma_9b, seamless_m4t_medium)
     archs = {}
     for mod in (internvl2_76b, qwen25_14b, gemma3_1b, qwen3_17b, qwen2_05b,
                 recurrentgemma_9b, mamba2_13b, arctic_480b, granite_moe_1b,
-                seamless_m4t_medium):
+                seamless_m4t_medium, deepseek_v2_lite):
         archs[mod.CONFIG.name] = mod.CONFIG
     for cfg in (llama.LLAMA_7B, llama.LLAMA_13B, llama.LLAMA_34B, llama.LLAMA_70B):
         archs[cfg.name] = cfg

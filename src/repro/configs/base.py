@@ -1,8 +1,9 @@
 """Unified model configuration covering every assigned architecture family.
 
-A model is a sequence of *scan groups*: ``superblock`` repeated
-``n_superblocks`` times (stacked + ``lax.scan``-ed) followed by an optional
-``tail`` group.  Every layer inside a superblock is one mixer
+A model is a sequence of *scan groups*: an optional ``lead`` group (layers
+ahead of the repeated pattern, such as DeepSeek's leading dense layer),
+``superblock`` repeated ``n_superblocks`` times (stacked + ``lax.scan``-ed),
+and an optional ``tail`` group.  Every layer inside a superblock is one mixer
 (attention / RG-LRU / Mamba2-SSD) plus an optional FFN, so heterogeneous
 patterns (Gemma-3 5:1 local:global, RecurrentGemma 2:1 lru:attn) scan cleanly.
 """
@@ -24,6 +25,9 @@ class LayerSpec:
     kind: str = ATTN  # attn | lru | ssm
     window: Optional[int] = None  # sliding-window size; None => full causal
     has_ffn: bool = True
+    # the gated MLP of width ``cfg.d_ff`` even in an MoE model (DeepSeek's
+    # leading dense layers)
+    dense_ffn: bool = False
 
     @property
     def is_local(self) -> bool:
@@ -45,6 +49,7 @@ class ModelConfig:
     superblock: tuple[LayerSpec, ...]
     n_superblocks: int
     tail: tuple[LayerSpec, ...] = ()
+    lead: tuple[LayerSpec, ...] = ()  # one group ahead of the superblocks
 
     # FFN flavour
     ffn_kind: str = "gated"  # gated | moe | none
@@ -52,6 +57,17 @@ class ModelConfig:
     top_k: int = 0
     expert_d_ff: int = 0
     dense_residual_ffn: bool = False  # Arctic: dense MLP in parallel with MoE
+    # DeepSeekMoE: shared experts, a SwiGLU of n_shared_experts *
+    # expert_d_ff that every token goes through
+    n_shared_experts: int = 0
+    # renormalise the top-k router weights over the row's k experts
+    # (granite, arctic); DeepSeek-V2 keeps the softmax probabilities
+    norm_topk_prob: bool = True
+    # Expert parallelism: the layer holds experts [expert_offset,
+    # expert_offset + n_local_experts) of the router's n_experts and
+    # computes only their part of the result; 0 holds them all
+    n_local_experts: int = 0
+    expert_offset: int = 0
     # MoE dispatch mode: "dropless" (cohort-independent grouped dispatch —
     # decode bit-matches the training forward) or "capacity" (legacy (E, C, D)
     # capacity-drop buffers, kept for training-parity experiments).
@@ -62,6 +78,22 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 1e4
     act: str = "silu"  # silu | gelu
+
+    # Multi-head latent attention (DeepSeek-V2), on when kv_lora_rank > 0:
+    # K/V come from a kv_lora_rank-wide latent, RoPE from a separate
+    # qk_rope_head_dim key shared by all heads; head_dim is then
+    # qk_nope_head_dim + qk_rope_head_dim (the q/k width)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN RoPE scaling, on when yarn_factor > 1
+    yarn_factor: float = 0.0
+    yarn_orig_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # Mamba2 / SSD
     ssm_state: int = 0
@@ -87,17 +119,42 @@ class ModelConfig:
 
     # ---------------------------------------------------------------- sanity
     def __post_init__(self):
-        n = len(self.superblock) * self.n_superblocks + len(self.tail)
+        n = (len(self.lead) + len(self.superblock) * self.n_superblocks
+             + len(self.tail))
         assert n == self.num_layers, (
             f"{self.name}: pattern covers {n} layers != num_layers={self.num_layers}")
         if self.family != "encdec":
             assert self.enc_layers == 0
         assert self.moe_dispatch in ("dropless", "capacity"), self.moe_dispatch
+        if self.kv_lora_rank:
+            assert self.head_dim == (self.qk_nope_head_dim
+                                     + self.qk_rope_head_dim), self.name
+        # YaRN's cos/sin scale mscale / mscale_all_dim is 1 (DeepSeek-V2);
+        # its temperature enters the softmax scale (models/mla.py)
+        assert self.yarn_factor <= 1 or (
+            self.yarn_mscale == self.yarn_mscale_all_dim), self.name
+        assert 0 <= self.expert_offset and (
+            self.expert_offset + self.local_experts <= self.n_experts), \
+            f"{self.name}: held experts beyond the router's {self.n_experts}"
 
     # ------------------------------------------------------------ properties
     @property
     def layers(self) -> list[LayerSpec]:
-        return list(self.superblock) * self.n_superblocks + list(self.tail)
+        return (list(self.lead) + list(self.superblock) * self.n_superblocks
+                + list(self.tail))
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def local_experts(self) -> int:
+        """Routed experts this layer holds."""
+        return self.n_local_experts or self.n_experts
+
+    @property
+    def shared_d_ff(self) -> int:
+        return self.n_shared_experts * self.expert_d_ff
 
     @property
     def q_dim(self) -> int:
@@ -126,6 +183,11 @@ class ModelConfig:
     # --------------------------------------------------------- param counts
     def attn_params(self, spec: LayerSpec) -> int:
         d, q, kv = self.d_model, self.q_dim, self.kv_dim
+        if self.is_mla:
+            r, h = self.kv_lora_rank, self.n_heads
+            return (d * q + d * (r + self.qk_rope_head_dim) + r
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
         p = d * q + 2 * d * kv + q * d  # wq, wk, wv, wo
         if self.qkv_bias:
             p += q + 2 * kv
@@ -133,17 +195,18 @@ class ModelConfig:
             p += 2 * self.head_dim
         return p
 
-    def ffn_params(self, active_only: bool = False) -> int:
+    def ffn_params(self, active_only: bool = False,
+                   dense: bool = False) -> int:
         d = self.d_model
         if self.ffn_kind == "none":
             return 0
-        if self.ffn_kind == "moe":
+        if self.ffn_kind == "moe" and not dense:
             per_expert = 3 * d * self.expert_d_ff
-            n = self.top_k if active_only else self.n_experts
+            n = self.top_k if active_only else self.local_experts
             p = n * per_expert + d * self.n_experts  # experts + router
             if self.dense_residual_ffn:
                 p += 3 * d * self.d_ff
-            return p
+            return p + 3 * d * self.shared_d_ff
         return 3 * d * self.d_ff  # gated: w_in, w_gate, w_out
 
     def lru_params(self) -> int:
@@ -168,7 +231,7 @@ class ModelConfig:
         else:
             p = self.ssm_params()
         if spec.has_ffn and self.ffn_kind != "none":
-            p += self.ffn_params(active_only) + self.d_model
+            p += self.ffn_params(active_only, spec.dense_ffn) + self.d_model
         return p + norms
 
     def param_count(self, active_only: bool = False) -> int:
@@ -194,11 +257,16 @@ class ModelConfig:
         """A tiny config of the same family for CPU smoke tests."""
         n_sb = min(self.n_superblocks, 2)
         tail = self.tail
-        num_layers = len(self.superblock) * n_sb + len(tail)
+        num_layers = len(self.lead) + len(self.superblock) * n_sb + len(tail)
         head_dim = 16
         n_heads = min(self.n_heads, 4)
         n_kv = max(1, min(self.n_kv_heads, 2))
         d_model = 64
+        mla = {}
+        if self.is_mla:  # every head has its own K/V, from a 32-wide latent
+            n_kv = n_heads
+            mla = dict(kv_lora_rank=32, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16, head_dim=24)
         kw = dict(
             name=self.name + "-smoke",
             num_layers=num_layers,
@@ -224,8 +292,11 @@ class ModelConfig:
             tail=tuple(
                 dataclasses.replace(s, window=min(s.window, 16) if s.window else None)
                 for s in self.tail),
+            n_local_experts=0,
+            expert_offset=0,
             dtype="float32",  # CPU smoke tests run in fp32
         )
+        kw.update(mla)
         kw.update(overrides)
         return dataclasses.replace(self, **kw)
 

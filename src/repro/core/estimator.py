@@ -147,8 +147,10 @@ class CostModel:
 
     def __init__(self, cluster: Cluster, profile: Profile | None = None,
                  table=None, type_scales: dict[str, float] | None = None,
-                 realloc_scale: float = 1.0):
+                 realloc_scale: float = 1.0, adam_bytes: float = ADAM_BYTES):
         self.cluster = cluster
+        # optimizer bytes a parameter keeps resident (moments and master)
+        self.adam_bytes = adam_bytes
         self.prof = profile or Profile()
         self.table = table
         self.type_scales = dict(type_scales or {})
@@ -503,7 +505,7 @@ class CostModel:
         n = cfg.param_count()
         denom = asg.strategy.size if opt_shard_dp else (
             asg.strategy.tp * asg.strategy.pp)
-        return (n * ADAM_BYTES) / denom + n * GRAD_BYTES / (
+        return (n * self.adam_bytes) / denom + n * GRAD_BYTES / (
             asg.strategy.tp * asg.strategy.pp)
 
     def active_mem_per_dev(self, call: FunctionCall, asg: Assignment) -> float:

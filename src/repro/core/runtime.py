@@ -271,6 +271,9 @@ class RuntimeEngine:
         self._recorded_upto = 0  # records already folded into the cost model
         self._template = None  # cached (intra, cross) dependency structure
         self.records: list[CallRecord] = []
+        # per call name, the program counters its executors added
+        # (core/tracing.count), summed on the device as they come
+        self.counters: dict[str, dict] = {}
         tracing.install()
         self._outside0 = tracing.outside()  # "(outside)" counts from here
         self._dev_locks: dict[int, asyncio.Lock] = {}
@@ -960,9 +963,11 @@ class RuntimeEngine:
             fn = self.executors.get(call.name) \
                 or self.executors[base_name(call.name)]
             compiles = tracing.Compiles()  # summed over attempts
+            counters: dict = {}
 
             def work():
-                with tracing.attribute(compiles), tracing.span(
+                with tracing.attribute(compiles), tracing.counting(
+                        counters), tracing.span(
                         "rt.exec", call=call.name, iteration=abs_iter,
                         attempt=attempts):
                     # chaos injection fires in the executor thread, exactly
@@ -1022,6 +1027,9 @@ class RuntimeEngine:
                 spec_won=spec["won"], traces=compiles.traces,
                 lowerings=compiles.lowerings,
                 compile_s=compiles.compile_s))
+            if counters:
+                tracing.add_counts(self.counters.setdefault(
+                    base_name(call.name), {}), counters)
         finally:
             for lk in reversed(locks):
                 lk.release()
@@ -1506,6 +1514,7 @@ class RuntimeEngine:
         return time.monotonic() - t0, moved
 
     def stats(self) -> dict:
+        import jax
         if not self.records:
             return {}
         t0 = min(r.start for r in self.records)
@@ -1521,9 +1530,13 @@ class RuntimeEngine:
             agg["traces"] += r.traces
             agg["lowerings"] += r.lowerings
             agg["compile_s"] += r.compile_s
-        for agg in calls.values():
+        for name, agg in calls.items():
             agg["total_s"] = round(agg["total_s"], 4)
             agg["mean_s"] = round(agg["total_s"] / agg["count"], 4)
+            # the counters as (nested) lists; reading them syncs
+            agg.update(jax.tree.map(lambda x: jax.device_get(x).tolist(),
+                                    getattr(self, "counters", {}).get(
+                                        name, {})))
         # compiles in no call's executor (realloc, the caller's own jits)
         # since this engine was built
         out = tracing.outside().minus(

@@ -16,6 +16,15 @@ JAX traces, lowers and compiles in the thread that calls the function, so a
 record made current inside a call's executor thread sees exactly that call's
 compiles (``run_in_executor`` carries no ``contextvars``, hence a
 thread-local).
+
+Program counters follow the same thread-local pattern: ``count(name,
+tree)`` adds a tree of (device) arrays into the dict that the calling
+thread made current with ``counting``; the runtime makes one current for
+each call's executor and adds it into that call's running total
+(``RuntimeEngine.counters``), which ``stats()["calls"]`` reports.  The MoE
+executors count their routing this way (``moe``: ``routed``, ``held`` and
+``held_max`` assignments, each per MoE layer).  Adding costs no host sync;
+reading does, in ``stats()``.
 """
 
 from __future__ import annotations
@@ -99,6 +108,32 @@ def attribute(rec: Compiles):
         yield rec
     finally:
         _local.compiles = prev
+
+
+@contextlib.contextmanager
+def counting(rec: dict):
+    """Add this thread's ``count`` calls into ``rec`` inside the block."""
+    prev = getattr(_local, "counters", None)
+    _local.counters = rec
+    try:
+        yield rec
+    finally:
+        _local.counters = prev
+
+
+def add_counts(total: dict, counts: dict) -> None:
+    """Add each of ``counts``' trees into ``total``'s tree of that name."""
+    for name, tree in counts.items():
+        total[name] = (tree if name not in total
+                       else jax.tree.map(lambda a, b: a + b, total[name], tree))
+
+
+def count(name: str, tree) -> None:
+    """Add ``tree`` into the current counters' ``name`` (a no-op where no
+    ``counting`` block is current, or for an empty tree)."""
+    rec = getattr(_local, "counters", None)
+    if rec is not None and jax.tree.leaves(tree):
+        add_counts(rec, {name: tree})
 
 
 def outside() -> Compiles:

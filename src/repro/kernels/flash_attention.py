@@ -112,14 +112,15 @@ def _tile_bounds(pos, block):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "interpret"))
+    "causal", "window", "scale", "block_q", "block_k", "interpret"))
 def flash_mha(q, k, v, *, causal=True, window=None, q_positions=None,
-              kv_positions=None, block_q=128, block_k=128, interpret=False):
+              kv_positions=None, scale=None, block_q=128, block_k=128,
+              interpret=False):
     """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D).  Returns (B, Sq, Hq, D).
 
     ``q_positions`` (B|1, Sq) / ``kv_positions`` (B|1, Skv): the logical
     positions the causal and window masks compare (default arange), as in
-    ``ref.mha_ref``."""
+    ``ref.mha_ref``.  ``scale`` multiplies the scores (default D**-0.5)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     g = hq // hkv
@@ -149,7 +150,8 @@ def flash_mha(q, k, v, *, causal=True, window=None, q_positions=None,
     klo, khi = _tile_bounds(kpos, block_k)
 
     kernel = functools.partial(
-        _kernel, scale=1.0 / (d ** 0.5), block_k=block_k, n_q=n_q, n_k=n_k,
+        _kernel, scale=1.0 / (d ** 0.5) if scale is None else scale,
+        block_k=block_k, n_q=n_q, n_k=n_k,
         causal=causal, window=window, seq_k=skv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,

@@ -106,6 +106,7 @@ def _forward(xs, group_sizes, w_gate, w_in, w_out, act, block_rows, block_ff,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="grouped_ffn",
     )(*meta, xs, w_gate, w_in, w_out)
     return out[:n]
 

@@ -29,17 +29,26 @@ def _check(impl):
 
 
 def mha(q, k, v, *, causal=True, window=None, q_positions=None,
-        kv_positions=None, impl="reference"):
+        kv_positions=None, scale=None, impl="reference"):
+    """q: (B, Sq, Hq, D); k: (B, Skv, Hkv, D); v: (B, Skv, Hkv, Dv).
+    ``scale`` multiplies the scores (default D**-0.5).  Returns
+    (B, Sq, Hq, Dv)."""
     _check(impl)
     if impl == "stub":
         return q + 0.0 * (k.sum() + v.sum())
     if impl == "reference":
         return ref.mha_ref(q, k, v, causal=causal, window=window,
-                           q_positions=q_positions, kv_positions=kv_positions)
+                           q_positions=q_positions, kv_positions=kv_positions,
+                           scale=scale)
     from repro.kernels import flash_attention
-    return flash_attention.flash_mha(
+    dv = v.shape[-1]
+    if dv != q.shape[-1]:  # the kernel takes one width: zero columns of v
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - dv),))
+    out = flash_attention.flash_mha(
         q, k, v, causal=causal, window=window, q_positions=q_positions,
-        kv_positions=kv_positions, interpret=(impl == "pallas_interpret"))
+        kv_positions=kv_positions, scale=scale,
+        interpret=(impl == "pallas_interpret"))
+    return out[..., :dv] if dv != q.shape[-1] else out
 
 
 def varlen_mha(q, k, v, cu_seqlens, *, causal=True, window=None,

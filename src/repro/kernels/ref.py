@@ -19,10 +19,11 @@ NEG_INF = -2.0**30  # large-but-finite; avoids NaN from all-masked rows
 
 def mha_ref(q, k, v, *, causal: bool = True, window: int | None = None,
             q_positions=None, kv_positions=None, logits_dtype=jnp.float32,
-            q_chunk: int | None = 0):
+            q_chunk: int | None = 0, scale: float | None = None):
     """Multi-head attention with grouped KV heads.
 
-    q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D) with Hq % Hkv == 0.
+    q: (B, Sq, Hq, D); k: (B, Skv, Hkv, D); v: (B, Skv, Hkv, Dv) with
+    Hq % Hkv == 0.  ``scale`` multiplies the scores (default D**-0.5).
     ``window``: sliding-window size (keys with q_pos - k_pos >= window masked).
     Positions default to arange; pass explicitly for decode / ring caches.
 
@@ -30,7 +31,7 @@ def mha_ref(q, k, v, *, causal: bool = True, window: int | None = None,
     is (B, H, q_chunk, Skv) instead of (B, H, Sq, Skv) — exact math, bounded
     memory, and no extra ``while`` loop (keeps HLO cost accounting simple).
     0 = auto (chunk long sequences); None = never chunk.
-    Returns (B, Sq, Hq, D) in q.dtype.
+    Returns (B, Sq, Hq, Dv) in q.dtype.
     """
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -60,12 +61,15 @@ def mha_ref(q, k, v, *, causal: bool = True, window: int | None = None,
                 q[:, lo:hi], k[:, klo:khi], v[:, klo:khi], causal=causal,
                 window=window, q_positions=q_positions[:, lo:hi],
                 kv_positions=kv_positions[:, klo:khi],
-                logits_dtype=logits_dtype, q_chunk=None))
+                logits_dtype=logits_dtype, q_chunk=None, scale=scale))
         return jnp.concatenate(outs, axis=1)
 
     qr = q.reshape(b, sq, hkv, g, d)
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qr, k).astype(logits_dtype)
-    logits = logits / jnp.sqrt(d).astype(logits_dtype)
+    if scale is None:
+        logits = logits / jnp.sqrt(d).astype(logits_dtype)
+    else:
+        logits = logits * jnp.asarray(scale, logits_dtype)
 
     dq = q_positions[:, None, None, :, None]  # (b,1,1,sq,1)
     dk = kv_positions[:, None, None, None, :]  # (b,1,1,1,skv)
@@ -77,7 +81,7 @@ def mha_ref(q, k, v, *, causal: bool = True, window: int | None = None,
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v)
-    return out.reshape(b, sq, hq, d)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 def _varlen_block(q, k, v, seg_q, seg_k, pos_q, pos_k, *, causal, window,
@@ -232,16 +236,14 @@ def expert_ids_of(group_sizes, n: int):
 
 def row_tiles(n: int, block_rows: int) -> tuple[int, int]:
     """(bn, n_pad): the 8-aligned row-tile size (<= block_rows) and padded
-    row count.  One definition shared by the oracle's scan regime and the
-    Pallas kernel so both walk the identical unit schedule."""
+    row count of the Pallas grouped expert kernel's unit schedule."""
     bn = min(block_rows, max(8, -(-n // 8) * 8))
     return bn, -(-n // bn) * bn
 
 
 def group_metadata(group_sizes, n_pad: int, bn: int):
-    """Per-work-unit dispatch metadata for the grouped expert GEMM (shared
-    by the jnp oracle below and the Pallas kernel's scalar prefetch; all
-    shapes static).
+    """Per-work-unit dispatch metadata for the grouped expert GEMM (the
+    Pallas kernel's scalar prefetch; all shapes static).
 
     Rows are tiled into ``bn``-row m-tiles; a tile straddling a group
     boundary is processed once per group it intersects, so the worst case
@@ -282,7 +284,7 @@ def group_metadata(group_sizes, n_pad: int, bn: int):
 
 
 def grouped_ffn_ref(xs, group_sizes, w_gate, w_in, w_out, *, act="silu",
-                    block_rows: int = 64, gather_limit: int = 1 << 22):
+                    gather_limit: int = 1 << 22):
     """Grouped gated expert FFN over expert-sorted rows (dropless MoE).
 
     xs: (N, D) rows already sorted by expert; group_sizes: (E,) int32 rows
@@ -297,11 +299,10 @@ def grouped_ffn_ref(xs, group_sizes, w_gate, w_in, w_out, *, act="silu",
     Two regimes (same per-row math, chosen by static shape):
       * small N x D x F (decode steps, CPU tests): per-row weight gather —
         exactly N rows of work, nothing expert-count-shaped.
-      * large (training cohorts, dry-run lowering): a scan over the same
-        boundary-spanning work units as the Pallas kernel, so the working
-        set stays one (D, F) expert slab + one (bn, D) row tile per step
-        (the gather would materialize N x D x F) and empty units are
-        skipped via ``lax.cond``.
+      * large (training cohorts, dry-run lowering): ``lax.ragged_dot``, one
+        grouped matmul per projection over the sorted rows (the gather
+        would materialize N x D x F), differentiable with no per-group
+        residuals.
     """
     n, d = xs.shape
     f32 = jnp.float32
@@ -316,36 +317,16 @@ def grouped_ffn_ref(xs, group_sizes, w_gate, w_in, w_out, *, act="silu",
         in_group = jnp.arange(n) < jnp.sum(group_sizes)
         return jnp.where(in_group[:, None], y, 0.0)
 
-    bn, n_pad = row_tiles(n, block_rows)
-    xt = jnp.pad(xs.astype(f32),
-                 ((0, n_pad - n), (0, 0))).reshape(n_pad // bn, bn, d)
-    ug, ut, lo, hi, _ = group_metadata(group_sizes, n_pad, bn)
-
-    def compute(inp):
-        ugi, uti, loi, hii = inp
-        x = jax.lax.dynamic_index_in_dim(xt, uti, 0, keepdims=False)
-        wg = jax.lax.dynamic_index_in_dim(w_gate, ugi, 0,
-                                          keepdims=False).astype(f32)
-        wi = jax.lax.dynamic_index_in_dim(w_in, ugi, 0,
-                                          keepdims=False).astype(f32)
-        wo = jax.lax.dynamic_index_in_dim(w_out, ugi, 0,
-                                          keepdims=False).astype(f32)
-        g = act_fn(x @ wg)
-        h = g * (x @ wi)
-        y = h @ wo  # (bn, d)
-        rows = uti * bn + jnp.arange(bn)
-        return jnp.where(((rows >= loi) & (rows < hii))[:, None], y, 0.0)
-
-    def unit(out, inp):
-        _, uti, loi, hii = inp
-        y = jax.lax.cond(loi < hii, compute,
-                         lambda _: jnp.zeros((bn, d), f32), inp)
-        tile = jax.lax.dynamic_index_in_dim(out, uti, 0, keepdims=False)
-        return jax.lax.dynamic_update_index_in_dim(out, tile + y, uti, 0), None
-
-    out0 = jnp.zeros((n_pad // bn, bn, d), f32)
-    out, _ = jax.lax.scan(unit, out0, (ug, ut, lo, hi))
-    return out.reshape(n_pad, d)[:n]
+    gs = group_sizes.astype(jnp.int32)
+    # ragged_dot leaves rows past the groups undefined (on the chip; zero
+    # on the CPU): zero them going in, between the projections and coming
+    # out, so that neither they nor their gradients reach a row in a group
+    valid = (jnp.arange(n) < jnp.sum(gs))[:, None]
+    x = jnp.where(valid, xs.astype(f32), 0.0)
+    wg, wi, wo = (w.astype(f32) for w in (w_gate, w_in, w_out))
+    g = act_fn(jax.lax.ragged_dot(x, wg, gs))
+    h = jnp.where(valid, g * jax.lax.ragged_dot(x, wi, gs), 0.0)
+    return jnp.where(valid, jax.lax.ragged_dot(h, wo, gs), 0.0)
 
 
 # ---------------------------------------------------------------------------

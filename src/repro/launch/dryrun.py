@@ -237,7 +237,7 @@ def probe_costs(cell: CellSpec):
             def dec_probe(xx, gp, cache):
                 with ctx.use(mesh, b_axes, rules.tp_axis):
                     for i, s in enumerate(specs):
-                        xx, cache[f"b{i}"] = T.block_decode(
+                        xx, cache[f"b{i}"], _ = T.block_decode(
                             gp[f"b{i}"], cfg, s, xx, cache[f"b{i}"],
                             jnp.int32(shape.seq_len - 1), jnp.int32(0),
                             impl="reference")
@@ -263,8 +263,8 @@ def probe_costs(cell: CellSpec):
                 pos = jnp.arange(sl)[None, :]
                 xx = ctx.constrain(xx, ctx.BATCH, None, None)
                 for i, s in enumerate(specs):
-                    xx, _, _ = T.block_apply(gp[f"b{i}"], cfg, s, xx, pos,
-                                             impl="reference")
+                    xx, *_ = T.block_apply(gp[f"b{i}"], cfg, s, xx, pos,
+                                           impl="reference")
                 return xx
 
         j = jax.jit(fwd_probe, in_shardings=(xsh, bsh))

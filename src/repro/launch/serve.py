@@ -253,8 +253,8 @@ class ContinuousBatchServer:
             self.compiles += 1
 
             def run(p, caches, batch, slots, table_rows, key):
-                last_h, dense = MDL.prefill(p, cfg, batch, max_len=plen,
-                                            impl=self.impl)
+                last_h, dense, _ = MDL.prefill(p, cfg, batch, max_len=plen,
+                                               impl=self.impl)
                 logits0 = MDL.logits_of(p, cfg, last_h[:, None])[:, 0]
                 tok0, lp0 = ops.sample_logits(
                     logits0, key if sampled else None,

@@ -7,8 +7,11 @@ norms and softmax run in fp32 regardless of the param/activation dtype.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels.ref import ACTS
@@ -47,11 +50,14 @@ def rmsnorm_apply(p, x, eps=1e-6):
 
 # ----------------------------------------------------------------- RoPE
 
-def rope_apply(x, positions, theta: float):
-    """x: (B, S, H, D) with even D; positions: (B, S) or (S,)."""
+def rope_apply(x, positions, theta: float, freqs=None):
+    """x: (B, S, H, D) with even D; positions: (B, S) or (S,).  ``freqs``
+    (D/2,) replaces the plain theta**(-2i/D) frequencies (YaRN)."""
     d = x.shape[-1]
     assert d % 2 == 0
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (D/2,)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    freqs = jnp.asarray(freqs, jnp.float32)  # (D/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B,S,D/2)
@@ -60,6 +66,56 @@ def rope_apply(x, positions, theta: float):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (DeepSeek's ``yarn_get_mscale``)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, orig_max_pos: int,
+                          beta_fast: float, beta_slow: float
+                          ) -> tuple[int, int]:
+    """The rotary pairs [low, high] over which YaRN ramps from the
+    extrapolated frequency to the interpolated one: pair i turns
+    beta_fast (beta_slow) times over ``orig_max_pos`` positions at low
+    (high)."""
+    def pair(turns):
+        return (dim * math.log(orig_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), dim - 1)
+    return low, high
+
+
+def rope_freqs(cfg: ModelConfig, dim: int):
+    """(dim/2,) rotary frequencies of a ``dim``-wide rotary part: None for
+    plain RoPE (``rope_apply``'s default), else YaRN's blend of
+    theta**(-2i/dim) and the same over ``yarn_factor``.  Cos and sin keep
+    unit scale: DeepSeek-V2 sets mscale == mscale_all_dim, whose ratio
+    scales them, and folds the temperature into the softmax scale."""
+    if cfg.yarn_factor <= 1:
+        return None
+    extra = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_correction_range(dim, cfg.rope_theta,
+                                      cfg.yarn_orig_max_pos,
+                                      cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp  # 1: extrapolate (high frequencies), 0: interpolate
+    return (extra * keep + extra / cfg.yarn_factor * (1 - keep)).astype(
+        np.float32)
+
+
+# ------------------------------------------------------------ chunking
+
+def even_chunks(n: int, per_item: int, budget: int) -> int:
+    """The fewest equal chunks of ``n`` items of ``per_item`` each that keep
+    a chunk within ``budget``; 1 (no chunking) where only chunks of under a
+    quarter of the budget divide ``n``."""
+    for c in range(-(-n * per_item // budget), n + 1):
+        if n % c == 0:
+            return c if 4 * (n // c) * per_item >= budget else 1
+    return 1
 
 
 # ----------------------------------------------------------------- MLP

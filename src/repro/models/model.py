@@ -53,8 +53,8 @@ def init_params(key, cfg: ModelConfig, head: str = "lm"):
 
 def _encode(params, cfg: ModelConfig, frames, impl):
     pos = jnp.arange(frames.shape[1])[None, :]
-    h, _ = T.stack_apply(params["encoder"]["groups"], cfg, frames, pos,
-                         causal=False, impl=impl)
+    h, _, _ = T.stack_apply(params["encoder"]["groups"], cfg, frames, pos,
+                            causal=False, impl=impl)
     return L.rmsnorm_apply(params["encoder"]["final_norm"], h, cfg.norm_eps)
 
 
@@ -70,7 +70,8 @@ def _embed_inputs(params, cfg: ModelConfig, batch):
 
 def forward(params, cfg: ModelConfig, batch, *, impl="reference", remat=True,
             max_seqlen=None):
-    """Full-sequence forward.  Returns (hidden (B,S,D), aux_loss).
+    """Full-sequence forward.  Returns (hidden (B,S,D), aux_loss, the MoE
+    layers' routing counters ({} for models without MoE)).
 
     Packed mode: when ``batch`` has "cu_seqlens", its "tokens" are a (T,)
     packed cohort and "positions" the (T,) within-sequence positions.  The
@@ -84,20 +85,20 @@ def forward(params, cfg: ModelConfig, batch, *, impl="reference", remat=True,
         x = L.embed_apply(params["embed"],
                           batch["tokens"][None]).astype(cfg.dtype)
         x = ctx.constrain(x, ctx.BATCH, None, None)
-        h, aux = T.stack_apply(params["groups"], cfg, x,
-                               batch["positions"][None], causal=True,
-                               impl=impl, remat=remat,
-                               cu_seqlens=batch["cu_seqlens"],
-                               max_seqlen=max_seqlen)
-        return L.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps), aux
+        h, aux, counts = T.stack_apply(
+            params["groups"], cfg, x, batch["positions"][None], causal=True,
+            impl=impl, remat=remat, cu_seqlens=batch["cu_seqlens"],
+            max_seqlen=max_seqlen)
+        return (L.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps), aux,
+                counts)
     x = _embed_inputs(params, cfg, batch)
     pos = jnp.arange(x.shape[1])[None, :]
     enc_out = None
     if cfg.family == "encdec":
         enc_out = _encode(params, cfg, batch["frames"], impl)
-    h, aux = T.stack_apply(params["groups"], cfg, x, pos, causal=True,
-                           impl=impl, enc_out=enc_out, remat=remat)
-    return L.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps), aux
+    h, aux, counts = T.stack_apply(params["groups"], cfg, x, pos, causal=True,
+                                   impl=impl, enc_out=enc_out, remat=remat)
+    return L.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps), aux, counts
 
 
 def logits_of(params, cfg: ModelConfig, hidden):
@@ -113,7 +114,7 @@ def values_of(params, hidden):
 
 def lm_loss(params, cfg: ModelConfig, batch, *, impl="reference", remat=True,
             aux_weight=0.01):
-    hidden, aux = forward(params, cfg, batch, impl=impl, remat=remat)
+    hidden, aux, _ = forward(params, cfg, batch, impl=impl, remat=remat)
     head_fn = lambda h: logits_of(params, cfg, h)
     loss, _ = L.chunked_lm_head_loss(head_fn, hidden, batch["labels"],
                                      batch["mask"])
@@ -123,7 +124,8 @@ def lm_loss(params, cfg: ModelConfig, batch, *, impl="reference", remat=True,
 # ----------------------------------------------------------------- serving
 
 def prefill(params, cfg: ModelConfig, batch, max_len, *, impl="reference"):
-    """Run the prompt, fill caches, return (last_hidden (B,D), caches)."""
+    """Run the prompt, fill caches, return (last_hidden (B,D), caches, the
+    routing counters ({} for models without MoE))."""
     x = _embed_inputs(params, cfg, batch)
     pos = jnp.arange(x.shape[1])[None, :]
     enc_out = None
@@ -134,23 +136,23 @@ def prefill(params, cfg: ModelConfig, batch, max_len, *, impl="reference"):
         enc_len = enc_out.shape[1]
     caches = T.cache_init(cfg, x.shape[0], max_len, jnp.dtype(cfg.dtype),
                           cross=cross, enc_len=enc_len)
-    h, caches = T.stack_prefill(params["groups"], cfg, x, pos, caches,
-                                impl=impl, enc_out=enc_out)
+    h, caches, counts = T.stack_prefill(params["groups"], cfg, x, pos, caches,
+                                        impl=impl, enc_out=enc_out)
     h = L.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
-    return h[:, -1], caches
+    return h[:, -1], caches, counts
 
 
 def decode_step(params, cfg: ModelConfig, token, caches, t, *,
                 impl="reference"):
     """token: (B,) int32; t: scalar int32 (position of this token).
-    Returns (logits (B,V) fp32, new_caches)."""
+    Returns (logits (B,V) fp32, new_caches, the step's routing counters)."""
     x = L.embed_apply(params["embed"], token[:, None]).astype(cfg.dtype)
     cross = cfg.family == "encdec"
-    h, caches = T.stack_decode(params["groups"], cfg, x, caches, t,
-                               impl=impl, cross=cross)
+    h, caches, counts = T.stack_decode(params["groups"], cfg, x, caches, t,
+                                       impl=impl, cross=cross)
     h = L.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
     logits = logits_of(params, cfg, h)[:, 0]
-    return logits, caches
+    return logits, caches, counts
 
 
 def decode_and_sample_step(params, cfg: ModelConfig, token, caches, t, key,
@@ -161,13 +163,14 @@ def decode_and_sample_step(params, cfg: ModelConfig, token, caches, t, key,
     sampling the *next* token and its logprob from the produced logits,
     without materializing a full ``log_softmax`` (``ops.sample_logits``,
     including fused top-k/top-p truncation).  ``key=None`` means greedy.
-    Returns (next_token (B,), logprob (B,), new_caches) — nothing
-    vocab-sized escapes this function."""
-    logits, caches = decode_step(params, cfg, token, caches, t, impl=impl)
+    Returns (next_token (B,), logprob (B,), new_caches, the step's routing
+    counters) — nothing vocab-sized escapes this function."""
+    logits, caches, counts = decode_step(params, cfg, token, caches, t,
+                                         impl=impl)
     tok, lp = ops.sample_logits(logits, key, temperature=temperature,
                                 sampler=sampler, top_k=top_k, top_p=top_p,
                                 impl=impl)
-    return tok, lp, caches
+    return tok, lp, caches, counts
 
 
 def paged_decode_and_sample_step(params, cfg: ModelConfig, token, caches,
@@ -260,6 +263,11 @@ def generate(params, cfg: ModelConfig, batch, *, num_new_tokens: int,
     ``top_k`` / ``top_p`` truncate the sampling distribution inside the
     fused sampler (mask-then-renormalize, see ``ops.sample_logits``);
     returned logprobs stay full-distribution (PPO convention).
+
+    For MoE models the fused loops also return ``moe``: the routing
+    counters of the prefill and every decode step, summed in the loop's
+    carry ({"routed", "held", "held_max"}, each (L,); ``held_max`` sums
+    each step's busiest held expert).
     """
     if eos_id is not None and not fused:
         raise ValueError("eos_id requires the fused decode loop "
@@ -269,7 +277,7 @@ def generate(params, cfg: ModelConfig, batch, *, num_new_tokens: int,
                          "sampler (fused=True)")
     prompt_len = batch["tokens"].shape[1]
     max_len = prompt_len + num_new_tokens
-    last_h, caches = prefill(params, cfg, batch, max_len, impl=impl)
+    last_h, caches, moe0 = prefill(params, cfg, batch, max_len, impl=impl)
     logits0 = logits_of(params, cfg, last_h[:, None])[:, 0]
 
     keys = (jax.random.split(rng, num_new_tokens) if rng is not None
@@ -290,8 +298,8 @@ def generate(params, cfg: ModelConfig, batch, *, num_new_tokens: int,
             logits, caches, t = carry
             tok = sample(logits, key)
             lp = logp_of(logits, tok)
-            new_logits, caches = decode_step(params, cfg, tok, caches, t,
-                                             impl=impl)
+            new_logits, caches, _ = decode_step(params, cfg, tok, caches, t,
+                                                impl=impl)
             return (new_logits, caches, t + 1), (tok, lp)
 
         (_, caches, _), (toks, lps) = jax.lax.scan(
@@ -303,21 +311,26 @@ def generate(params, cfg: ModelConfig, batch, *, num_new_tokens: int,
                                   sampler=sampler, top_k=top_k, top_p=top_p,
                                   impl=impl)
 
+    def step(tok, caches, t, key, moe):
+        """One fused decode step; adds its routing counters to ``moe``."""
+        ntok, lp, caches, counts = decode_and_sample_step(
+            params, cfg, tok, caches, t, key, temperature=temperature,
+            sampler=sampler, top_k=top_k, top_p=top_p, impl=impl)
+        return ntok, lp, caches, jax.tree.map(jnp.add, moe, counts)
+
     if eos_id is None:
         def body(carry, key):
-            tok, caches, t = carry
-            ntok, lp, caches = decode_and_sample_step(
-                params, cfg, tok, caches, t,
-                key if rng is not None else None,
-                temperature=temperature, sampler=sampler, top_k=top_k,
-                top_p=top_p, impl=impl)
-            return (ntok, caches, t + 1), (ntok, lp)
+            tok, caches, t, moe = carry
+            ntok, lp, caches, moe = step(
+                tok, caches, t, key if rng is not None else None, moe)
+            return (ntok, caches, t + 1, moe), (ntok, lp)
 
-        (_, caches, _), (toks, lps) = jax.lax.scan(
-            body, (tok0, caches, jnp.int32(prompt_len)), keys[1:])
+        (_, caches, _, moe), (toks, lps) = jax.lax.scan(
+            body, (tok0, caches, jnp.int32(prompt_len), moe0), keys[1:])
         tokens = jnp.concatenate([tok0[None], toks], axis=0).T
         logprobs = jnp.concatenate([lp0[None], lps], axis=0).T
-        return {"tokens": tokens, "logprobs": logprobs, "caches": caches}
+        res = {"tokens": tokens, "logprobs": logprobs, "caches": caches}
+        return {**res, "moe": moe} if moe else res
 
     # EOS-early-exit variant: fixed-shape (B, T) buffers, dynamic trip count
     b = tok0.shape[0]
@@ -325,30 +338,31 @@ def generate(params, cfg: ModelConfig, batch, *, num_new_tokens: int,
     lps_buf = jnp.zeros((b, num_new_tokens), jnp.float32)
     toks_buf = toks_buf.at[:, 0].set(tok0)
     lps_buf = lps_buf.at[:, 0].set(lp0)
-    state = (jnp.int32(1), tok0, tok0 == eos_id, caches, toks_buf, lps_buf)
+    state = (jnp.int32(1), tok0, tok0 == eos_id, caches, toks_buf, lps_buf,
+             moe0)
 
     def cond(s):
         i, _, done, *_ = s
         return jnp.logical_and(i < num_new_tokens, ~jnp.all(done))
 
     def wbody(s):
-        i, tok, done, caches, tb, lb = s
+        i, tok, done, caches, tb, lb, moe = s
         key = keys[i] if rng is not None else None
-        ntok, lp, caches = decode_and_sample_step(
-            params, cfg, tok, caches, prompt_len + i - 1, key,
-            temperature=temperature, sampler=sampler, top_k=top_k,
-            top_p=top_p, impl=impl)
+        ntok, lp, caches, moe = step(tok, caches, prompt_len + i - 1, key,
+                                     moe)
         ntok = jnp.where(done, eos_id, ntok)
         lp = jnp.where(done, 0.0, lp)
         tb = tb.at[:, i].set(ntok)
         lb = lb.at[:, i].set(lp)
-        return (i + 1, ntok, done | (ntok == eos_id), caches, tb, lb)
+        return (i + 1, ntok, done | (ntok == eos_id), caches, tb, lb, moe)
 
-    _, _, _, caches, toks_buf, lps_buf = jax.lax.while_loop(cond, wbody, state)
+    _, _, _, caches, toks_buf, lps_buf, moe = jax.lax.while_loop(
+        cond, wbody, state)
     is_eos = (toks_buf == eos_id).astype(jnp.int32)
     after_eos = (jnp.cumsum(is_eos, axis=1) - is_eos) > 0
-    return {"tokens": toks_buf, "logprobs": lps_buf, "caches": caches,
-            "gen_mask": 1.0 - after_eos.astype(jnp.float32)}
+    res = {"tokens": toks_buf, "logprobs": lps_buf, "caches": caches,
+           "gen_mask": 1.0 - after_eos.astype(jnp.float32)}
+    return {**res, "moe": moe} if moe else res
 
 
 # ----------------------------------------------------------- bucketed jit

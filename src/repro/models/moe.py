@@ -20,6 +20,19 @@
 
 Both paths accumulate the combine in fp32 and cast to the model dtype once
 at the end.  Arctic's dense-residual MLP rides alongside either scheme.
+
+DeepSeekMoE (dropless only): ``cfg.norm_topk_prob=False`` keeps the softmax
+probabilities of the top-k as the combine weights, and a shared expert
+(a SwiGLU of ``cfg.shared_d_ff``) sees every token.  Under expert
+parallelism the layer holds ``cfg.local_experts`` experts from
+``cfg.expert_offset``: the router still scores all ``cfg.n_experts``, the
+assignments to held experts go through the grouped GEMM, and the rest add
+nothing here (they are other chips' part of the result).
+
+``moe_apply`` also returns the routing counters of the pass, three int32
+scalars that add up over passes: ``routed``, the assignments made (T·k);
+``held``, those that landed on a held expert; and ``held_max``, those of
+the busiest held expert.
 """
 
 from __future__ import annotations
@@ -32,21 +45,27 @@ from repro.kernels import ops
 from repro.models import layers as L
 
 CAPACITY_FACTOR = 1.25
+# the dropless dispatch handles at most this many tokens at a time: it
+# gathers k rows a token, so a training cohort's dispatch and its gradient
+# are bounded by a chunk's rows, not the cohort's
+DISPATCH_CHUNK = 1024
 
 
 def moe_init(key, cfg: ModelConfig):
     dt = jnp.dtype(cfg.dtype)
-    ks = jax.random.split(key, 5)
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.expert_d_ff
+    ks = jax.random.split(key, 6)
+    e, d, f = cfg.local_experts, cfg.d_model, cfg.expert_d_ff
     scale = d ** -0.5
     p = {
-        "router": L.dense_init(ks[0], d, e, jnp.float32),
+        "router": L.dense_init(ks[0], d, cfg.n_experts, jnp.float32),
         "w_gate": L.truncated_normal(ks[1], (e, d, f), dt, scale),
         "w_in": L.truncated_normal(ks[2], (e, d, f), dt, scale),
         "w_out": L.truncated_normal(ks[3], (e, f, d), dt, f ** -0.5),
     }
     if cfg.dense_residual_ffn:
         p["dense"] = L.mlp_init(ks[4], cfg)
+    if cfg.n_shared_experts:
+        p["shared"] = L.mlp_init(ks[5], cfg, d_ff=cfg.shared_d_ff)
     return p
 
 
@@ -82,18 +101,47 @@ def _sort_by_expert(top_i, t: int, k: int):
 
 
 def _dispatch_dropless(p, cfg: ModelConfig, xf, top_w, top_i, impl):
-    """Grouped dropless dispatch: every assignment is honored, weights are
-    renormalized over the row's own k experts only (cohort independent)."""
+    """Grouped dropless dispatch: every assignment to a held expert is
+    honored; weights are renormalized over the row's own k experts only
+    (cohort independent), or kept as routed (``norm_topk_prob=False``).
+    A large cohort is dispatched in the fewest equal token chunks of at
+    most DISPATCH_CHUNK tokens (``layers.even_chunks``), each rematerialised in the backward pass; a token's result does not
+    depend on its chunk.  Returns (out (T, D), per held expert assignment
+    counts (E_local,))."""
     t, d = xf.shape
-    e, k = cfg.n_experts, cfg.top_k
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    order, _, st = _sort_by_expert(top_i, t, k)
+    c = L.even_chunks(t, 1, DISPATCH_CHUNK)
+    if c > 1:
+        def chunk(args):
+            return _dispatch_chunk(p, cfg, *args, impl)
+        k = top_i.shape[-1]
+        out, held = jax.lax.map(jax.checkpoint(chunk), (
+            xf.reshape(c, t // c, d), top_w.reshape(c, t // c, k),
+            top_i.reshape(c, t // c, k)))
+        return out.reshape(t, d), held.sum(0)
+    return _dispatch_chunk(p, cfg, xf, top_w, top_i, impl)
+
+
+def _dispatch_chunk(p, cfg: ModelConfig, xf, top_w, top_i, impl):
+    t, d = xf.shape
+    e, k = cfg.local_experts, cfg.top_k
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    share = e < cfg.n_experts
+    if share:  # experts not held here sort after the held ones, as group e
+        local = top_i - cfg.expert_offset
+        top_i = jnp.where((local >= 0) & (local < e), local, e)
+    order, se, st = _sort_by_expert(top_i, t, k)
     sw = top_w.reshape(t * k)[order].astype(jnp.float32)
-    group_sizes = jnp.zeros((e,), jnp.int32).at[top_i.reshape(-1)].add(1)
-    ys = ops.grouped_ffn(xf[st], group_sizes, p["w_gate"], p["w_in"],
-                         p["w_out"], act=cfg.act, impl=impl)  # (T*K, D) f32
-    out = jnp.zeros((t, d), jnp.float32).at[st].add(ys * sw[:, None])
-    return out.astype(xf.dtype)
+    group_sizes = jnp.zeros((e,), jnp.int32).at[top_i.reshape(-1)].add(
+        1, mode="drop")
+    with jax.named_scope("moe.experts"):
+        # at most T*K rows; the grouped GEMM skips what no held expert has
+        ys = ops.grouped_ffn(xf[st], group_sizes, p["w_gate"], p["w_in"],
+                             p["w_out"], act=cfg.act, impl=impl)  # f32
+        if share:  # rows past the held groups are undefined in the kernel
+            ys = jnp.where((se < e)[:, None], ys, 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[st].add(ys * sw[:, None])
+    return out.astype(xf.dtype), group_sizes
 
 
 def capacity_route(cfg: ModelConfig, top_w, top_i, t: int):
@@ -140,7 +188,8 @@ def _dispatch_capacity(p, cfg: ModelConfig, xf, top_w, top_i):
 
 
 def moe_apply(p, cfg: ModelConfig, x, *, impl="reference", want_aux=True):
-    """x: (B, S, D) -> (B, S, D) plus aux load-balancing loss.
+    """x: (B, S, D) -> (B, S, D) plus aux load-balancing loss and the
+    routing counters ({"routed", "held", "held_max"}, module doc).
 
     ``want_aux=False`` (serving paths: prefill/decode) skips the aux-loss
     computation entirely — it is dead work outside the training forward —
@@ -149,16 +198,26 @@ def moe_apply(p, cfg: ModelConfig, x, *, impl="reference", want_aux=True):
     t = b * s
     xf = x.reshape(t, d)
 
-    probs, top_w, top_i = _router(p, cfg, xf)
-    aux_loss = (_aux_loss(probs, top_i, cfg.n_experts) if want_aux
-                else jnp.zeros((), jnp.float32))
+    with jax.named_scope("moe.route"):
+        probs, top_w, top_i = _router(p, cfg, xf)
+        aux_loss = (_aux_loss(probs, top_i, cfg.n_experts) if want_aux
+                    else jnp.zeros((), jnp.float32))
 
     if cfg.moe_dispatch == "dropless":
-        out = _dispatch_dropless(p, cfg, xf, top_w, top_i, impl)
+        out, held = _dispatch_dropless(p, cfg, xf, top_w, top_i, impl)
     else:
+        if cfg.local_experts != cfg.n_experts or not cfg.norm_topk_prob:
+            raise NotImplementedError("capacity dispatch holds every expert "
+                                      "and renormalises")
         out = _dispatch_capacity(p, cfg, xf, top_w, top_i)
+        held = jnp.zeros((cfg.n_experts,), jnp.int32).at[
+            top_i.reshape(-1)].add(1)
     out = out.reshape(b, s, d)
 
     if "dense" in p:
         out = out + L.mlp_apply(p["dense"], cfg, x)
-    return out, aux_loss
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + L.mlp_apply(p["shared"], cfg, x)
+    return out, aux_loss, {"routed": jnp.int32(t * cfg.top_k),
+                           "held": held.sum(), "held_max": held.max()}

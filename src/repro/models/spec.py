@@ -130,8 +130,8 @@ def _admit_run(cfg: ModelConfig, prompt_len: int, sampled: bool,
     @jax.jit
     def run(params, batch, paged, table_rows, key):
         b = batch["tokens"].shape[0]
-        last_h, dense = MDL.prefill(params, cfg, batch, prompt_len,
-                                    impl=impl)
+        last_h, dense, _ = MDL.prefill(params, cfg, batch, prompt_len,
+                                       impl=impl)
         paged = PC.paged_insert(cfg, paged, dense, jnp.arange(b), table_rows,
                                 prompt_len)
         logits0 = MDL.logits_of(params, cfg, last_h[:, None])[:, 0]

@@ -10,6 +10,12 @@ LayerSpecs) whose params are stacked over ``n`` repeats and driven by
 
 Blocks: mixer (attention / RG-LRU / SSD) + optional FFN (gated MLP or MoE),
 with pre-norms; decoder blocks of enc-dec models add cross-attention.
+Attention is GQA (``models/attention.py``) or, with ``cfg.kv_lora_rank``,
+multi-head latent attention (``models/mla.py``) over a latent cache.
+
+The three paths also return the routing counters of every MoE layer, in
+layer order: ``routed``, ``held`` and ``held_max``, each (L,)
+(``moe.moe_apply``); an empty dict for models without MoE layers.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ import jax.numpy as jnp
 from repro.configs.base import ATTN, LRU, SSM, LayerSpec, ModelConfig
 from repro.models import attention as A
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import ssm as S
@@ -29,10 +36,30 @@ from repro.parallel import ctx
 
 
 def groups_of(cfg: ModelConfig) -> list[tuple[tuple[LayerSpec, ...], int]]:
-    gs = [(cfg.superblock, cfg.n_superblocks)]
+    gs = [(cfg.lead, 1)] if cfg.lead else []
+    gs.append((cfg.superblock, cfg.n_superblocks))
     if cfg.tail:
         gs.append((cfg.tail, 1))
     return gs
+
+
+def _is_moe(cfg: ModelConfig, spec: LayerSpec) -> bool:
+    return (spec.has_ffn and cfg.ffn_kind == "moe" and not spec.dense_ffn)
+
+
+def _layer_counts(ys):
+    """A scan's per-block counters ([{(n, ...)}] over the blocks of the
+    pattern that have them) as one tree in layer order."""
+    if not ys:
+        return {}
+    return jax.tree.map(
+        lambda *a: jnp.stack(a, 1).reshape(-1, *a[0].shape[1:]), *ys)
+
+
+def _merge_counts(per_group):
+    """Concatenate the groups' counters ({} where a group has none)."""
+    cs = [c for c in per_group if c]
+    return jax.tree.map(lambda *a: jnp.concatenate(a, 0), *cs) if cs else {}
 
 
 # ------------------------------------------------------------------- blocks
@@ -41,7 +68,9 @@ def block_init(key, cfg: ModelConfig, spec: LayerSpec, cross: bool = False):
     ks = jax.random.split(key, 6)
     dt = jnp.dtype(cfg.dtype)
     p = {"ln1": L.rmsnorm_init(cfg.d_model, dt)}
-    if spec.kind == ATTN:
+    if spec.kind == ATTN and cfg.is_mla:
+        p["mixer"] = MLA.mla_init(ks[0], cfg)
+    elif spec.kind == ATTN:
         p["mixer"] = A.attn_init(ks[0], cfg)
     elif spec.kind == LRU:
         p["mixer"] = R.lru_init(ks[0], cfg)
@@ -52,25 +81,48 @@ def block_init(key, cfg: ModelConfig, spec: LayerSpec, cross: bool = False):
         p["xattn"] = A.attn_init(ks[1], cfg, cross=True)
     if spec.has_ffn and cfg.ffn_kind != "none":
         p["ln2"] = L.rmsnorm_init(cfg.d_model, dt)
-        p["ffn"] = (M.moe_init(ks[2], cfg) if cfg.ffn_kind == "moe"
+        p["ffn"] = (M.moe_init(ks[2], cfg) if _is_moe(cfg, spec)
                     else L.mlp_init(ks[2], cfg))
     return p
 
 
-def _ffn(p, cfg, x, *, impl="reference", want_aux=True):
+# a dense FFN over more than this many hidden activations (tokens x d_ff)
+# runs in token chunks, each rematerialised in the backward pass, so that
+# its working set is a chunk's
+FFN_CHUNK_ACTIVATIONS = 1 << 26
+
+
+def _mlp(p, cfg, h):
+    """The dense FFN, in the fewest equal token chunks of at most
+    FFN_CHUNK_ACTIVATIONS (``layers.even_chunks``); a token's result does
+    not depend on its chunk."""
+    b, s, d = h.shape
+    t = b * s
+    c = L.even_chunks(t, cfg.d_ff, FFN_CHUNK_ACTIVATIONS)
+    if c == 1:
+        return L.mlp_apply(p, cfg, h)
+    y = jax.lax.map(jax.checkpoint(lambda x: L.mlp_apply(p, cfg, x)),
+                    h.reshape(c, t // c, d))
+    return y.reshape(b, s, d)
+
+
+def _ffn(p, cfg, spec, x, *, impl="reference", want_aux=True):
+    """Returns (x, aux, routing counters ({} without MoE))."""
     if "ffn" not in p:
-        return x, 0.0
+        return x, 0.0, {}
     h = L.rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
-    if cfg.ffn_kind == "moe":
-        y, aux = M.moe_apply(p["ffn"], cfg, h, impl=impl, want_aux=want_aux)
-        return x + y, aux
-    return x + L.mlp_apply(p["ffn"], cfg, h), 0.0
+    if _is_moe(cfg, spec):
+        y, aux, counts = M.moe_apply(p["ffn"], cfg, h, impl=impl,
+                                     want_aux=want_aux)
+        return x + y, aux, counts
+    return x + _mlp(p["ffn"], cfg, h), 0.0, {}
 
 
 def block_apply(p, cfg, spec, x, positions, *, causal=True, impl="reference",
                 enc_out=None, want_state=False, cu_seqlens=None,
                 max_seqlen=None):
-    """Full-sequence block.  Returns (x, aux_loss, state_or_None).
+    """Full-sequence block.  Returns (x, aux_loss, state_or_None, routing
+    counters ({} without MoE)).
 
     Packed mode (``cu_seqlens`` given): attention goes block-diagonal over
     the packed segments; norms/FFN/MoE are per-token and need no change.
@@ -81,7 +133,13 @@ def block_apply(p, cfg, spec, x, positions, *, causal=True, impl="reference",
             f"packed training is attention-only; got mixer kind {spec.kind}")
     h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     state = None
-    if spec.kind == ATTN:
+    if spec.kind == ATTN and cfg.is_mla:
+        if cu_seqlens is not None:
+            raise NotImplementedError("packed training has no MLA path")
+        out = MLA.mla_apply(p["mixer"], cfg, h, positions, causal=causal,
+                            impl=impl, want_latent=want_state)
+        y, state = out if want_state else (out, None)
+    elif spec.kind == ATTN:
         if want_state:
             y, kv = A.attn_apply_with_kv(p["mixer"], cfg, spec, h, positions,
                                          causal=causal, impl=impl)
@@ -100,8 +158,8 @@ def block_apply(p, cfg, spec, x, positions, *, causal=True, impl="reference",
     if enc_out is not None:
         hx = L.rmsnorm_apply(p["lnx"], x, cfg.norm_eps)
         x = x + A.cross_attn_apply(p["xattn"], cfg, hx, enc_out, impl=impl)
-    x, aux = _ffn(p, cfg, x, impl=impl)
-    return x, aux, state
+    x, aux, counts = _ffn(p, cfg, spec, x, impl=impl)
+    return x, aux, state, counts
 
 
 def _layer_of(stack, layer):
@@ -118,12 +176,16 @@ def _set_layer(stack, new, layer):
 def block_decode(p, cfg, spec, x, cache, t, layer, *, impl="reference",
                  cross=False):
     """Single-token block step on layer ``layer`` of a scan group's stacked
-    cache (every leaf (L, ...)).  Returns (x, new_cache): the stack with
-    this layer's entries updated in place -- one token of K/V for attention,
-    the small recurrent state for LRU/SSM; cross-attention K/V is read."""
+    cache (every leaf (L, ...)).  Returns (x, new_cache, routing counters):
+    the stack with this layer's entries updated in place -- one token of
+    K/V for attention (of latent for MLA), the small recurrent state for
+    LRU/SSM; cross-attention K/V is read."""
     mixer_cache = cache["self"] if cross else cache
     h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
-    if spec.kind == ATTN:
+    if spec.kind == ATTN and cfg.is_mla:
+        y, new_mixer = MLA.mla_decode_apply(p["mixer"], cfg, h, mixer_cache,
+                                            t, layer)
+    elif spec.kind == ATTN:
         y, new_mixer = A.attn_decode_apply(p["mixer"], cfg, spec, h,
                                            mixer_cache, t, layer, impl=impl)
     else:
@@ -136,9 +198,9 @@ def block_decode(p, cfg, spec, x, cache, t, layer, *, impl="reference",
         x = x + A.cross_attn_apply(p["xattn"], cfg, hx,
                                    enc_kv=_layer_of(cache["xkv"], layer),
                                    impl=impl)
-    x, _ = _ffn(p, cfg, x, impl=impl, want_aux=False)
+    x, _, counts = _ffn(p, cfg, spec, x, impl=impl, want_aux=False)
     new_cache = {"self": new_mixer, "xkv": cache["xkv"]} if cross else new_mixer
-    return x, new_cache
+    return x, new_cache, counts
 
 
 def block_paged_decode(p, cfg, spec, x, cache, block_table, positions, *,
@@ -148,6 +210,8 @@ def block_paged_decode(p, cfg, spec, x, cache, block_table, positions, *,
     Full-attention layers write/read through the shared block pool via
     ``block_table``; window layers use their per-slot ring buffers;
     recurrent mixers are position-free.  Returns (x, new_cache)."""
+    if cfg.is_mla:
+        raise NotImplementedError("paged decode has no MLA path")
     h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     if spec.kind == ATTN:
         if spec.window is None:
@@ -162,7 +226,7 @@ def block_paged_decode(p, cfg, spec, x, cache, block_table, positions, *,
     else:
         y, new_cache = S.ssm_decode_apply(p["mixer"], cfg, h, cache)
     x = x + y
-    x, _ = _ffn(p, cfg, x, impl=impl, want_aux=False)
+    x, _, _ = _ffn(p, cfg, spec, x, impl=impl, want_aux=False)
     return x, new_cache
 
 
@@ -173,9 +237,9 @@ def block_paged_verify(p, cfg, spec, x, cache, block_table, positions, *,
     would need per-step state rollback on draft rejection, so they are
     rejected here (the spec-decode entry points gate on this upfront).
     Returns (x, new_cache)."""
-    if spec.kind != ATTN:
+    if spec.kind != ATTN or cfg.is_mla:
         raise NotImplementedError(
-            f"speculative verify is attention-only; got mixer kind "
+            f"speculative verify is GQA-attention-only; got mixer kind "
             f"{spec.kind}")
     h = L.rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     if spec.window is None:
@@ -186,7 +250,7 @@ def block_paged_verify(p, cfg, spec, x, cache, block_table, positions, *,
         y, new_cache = A.ragged_attn_verify_apply(
             p["mixer"], cfg, spec, h, cache, positions, impl=impl)
     x = x + y
-    x, _ = _ffn(p, cfg, x, impl=impl, want_aux=False)
+    x, _, _ = _ffn(p, cfg, spec, x, impl=impl, want_aux=False)
     return x, new_cache
 
 
@@ -210,29 +274,35 @@ def stack_init(key, cfg: ModelConfig, cross: bool = False):
 def stack_apply(groups_params, cfg: ModelConfig, x, positions, *, causal=True,
                 impl="reference", enc_out=None, remat=True, cu_seqlens=None,
                 max_seqlen=None):
+    """Returns (x, aux, the MoE layers' routing counters)."""
     aux_total = jnp.zeros((), jnp.float32)
+    per_group = []
     for (specs, n), gp in zip(groups_of(cfg), groups_params):
         def body(carry, layer_p, specs=specs):
             xc, aux = carry
             xc = ctx.constrain(xc, ctx.BATCH, None, None)
+            ys = []
             for i, spec in enumerate(specs):
-                xc, a, _ = block_apply(layer_p[f"b{i}"], cfg, spec, xc,
-                                       positions, causal=causal, impl=impl,
-                                       enc_out=enc_out,
-                                       cu_seqlens=cu_seqlens,
-                                       max_seqlen=max_seqlen)
+                xc, a, _, c = block_apply(
+                    layer_p[f"b{i}"], cfg, spec, xc, positions,
+                    causal=causal, impl=impl, enc_out=enc_out,
+                    cu_seqlens=cu_seqlens, max_seqlen=max_seqlen)
+                ys += [c] if c else []
                 aux = aux + a
-            return (xc, aux), None
+            return (xc, aux), ys
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), gp)
-    return x, aux_total
+        (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), gp)
+        per_group.append(_layer_counts(ys))
+    return x, aux_total, _merge_counts(per_group)
 
 
 def group_cache_init(cfg: ModelConfig, specs, n, batch, max_len, dtype,
                      cross=False, enc_len=None):
     def one(spec):
-        if spec.kind == ATTN:
+        if spec.kind == ATTN and cfg.is_mla:
+            c = MLA.cache_init(cfg, batch, max_len, dtype)
+        elif spec.kind == ATTN:
             c = A.cache_init(cfg, spec, batch, max_len, dtype)
         elif spec.kind == LRU:
             c = R.lru_state_init(cfg, batch, dtype)
@@ -256,20 +326,25 @@ def cache_init(cfg: ModelConfig, batch, max_len, dtype, cross=False,
 def stack_prefill(groups_params, cfg: ModelConfig, x, positions, caches, *,
                   impl="reference", enc_out=None):
     """Full forward that fills decode caches.  ``caches`` from cache_init.
-    A serving path: skips the (dead) MoE aux-loss work, returns (x, caches)."""
+    A serving path: skips the (dead) MoE aux-loss work, returns (x, caches,
+    routing counters)."""
     seq_len = x.shape[1]
-    new_caches = []
+    new_caches, per_group = [], []
     for (specs, n), gp, gc in zip(groups_of(cfg), groups_params, caches):
         def body(xc, inp, specs=specs):
             xc = ctx.constrain(xc, ctx.BATCH, None, None)
             layer_p, cache = inp
-            out_cache = {}
+            out_cache, ys = {}, []
             for i, spec in enumerate(specs):
                 p = layer_p[f"b{i}"]
                 bc = cache[f"b{i}"]
                 mixer_cache = bc["self"] if enc_out is not None else bc
                 h = L.rmsnorm_apply(p["ln1"], xc, cfg.norm_eps)
-                if spec.kind == ATTN:
+                if spec.kind == ATTN and cfg.is_mla:
+                    y, lat = MLA.mla_apply(p["mixer"], cfg, h, positions,
+                                           impl=impl, want_latent=True)
+                    new_mixer = MLA.prefill_into_cache(mixer_cache, lat)
+                elif spec.kind == ATTN:
                     y, kv = A.attn_apply_with_kv(p["mixer"], cfg, spec, h,
                                                  positions, causal=True,
                                                  impl=impl)
@@ -292,11 +367,13 @@ def stack_prefill(groups_params, cfg: ModelConfig, x, positions, caches, *,
                                               lambda a: a.astype(cfg.dtype), xkv)}
                 else:
                     out_cache[f"b{i}"] = new_mixer
-                xc, _ = _ffn(p, cfg, xc, impl=impl, want_aux=False)
-            return xc, out_cache
-        x, nc = jax.lax.scan(body, x, (gp, gc))
+                xc, _, c = _ffn(p, cfg, spec, xc, impl=impl, want_aux=False)
+                ys += [c] if c else []
+            return xc, (out_cache, ys)
+        x, (nc, ys) = jax.lax.scan(body, x, (gp, gc))
         new_caches.append(nc)
-    return x, new_caches
+        per_group.append(_layer_counts(ys))
+    return x, new_caches, _merge_counts(per_group)
 
 
 def stack_paged_decode(groups_params, cfg: ModelConfig, x, caches,
@@ -342,23 +419,27 @@ def stack_paged_verify(groups_params, cfg: ModelConfig, x, caches,
 
 def stack_decode(groups_params, cfg: ModelConfig, x, caches, t, *,
                  impl="reference", cross=False):
-    """x: (B, 1, D); t: scalar position.  Returns (x, new_caches).
+    """x: (B, 1, D); t: scalar position.  Returns (x, new_caches, the step's
+    routing counters).
 
     The layer scan takes the params as xs and carries each group's stacked
     caches with the layer index: every layer writes its token in place and
     reads its cache where it lies, so no step copies a layer's cache out of
     the stack or back into it."""
-    new_caches = []
+    new_caches, per_group = [], []
     for (specs, n), gp, gc in zip(groups_of(cfg), groups_params, caches):
         def body(carry, layer_p, specs=specs):
             xc, cache, layer = carry
             xc = ctx.constrain(xc, ctx.BATCH, None, None)
             cache = dict(cache)
+            ys = []
             for i, spec in enumerate(specs):
-                xc, cache[f"b{i}"] = block_decode(
+                xc, cache[f"b{i}"], c = block_decode(
                     layer_p[f"b{i}"], cfg, spec, xc, cache[f"b{i}"], t, layer,
                     impl=impl, cross=cross)
-            return (xc, cache, layer + 1), None
-        (x, gc, _), _ = jax.lax.scan(body, (x, gc, jnp.int32(0)), gp)
+                ys += [c] if c else []
+            return (xc, cache, layer + 1), ys
+        (x, gc, _), ys = jax.lax.scan(body, (x, gc, jnp.int32(0)), gp)
         new_caches.append(gc)
-    return x, new_caches
+        per_group.append(_layer_counts(ys))
+    return x, new_caches, _merge_counts(per_group)

@@ -44,7 +44,8 @@ def make_prefill_step(cfg: ModelConfig, *, impl="reference",
 
     def step(params, batch):
         max_len = batch["tokens"].shape[1] + max(extra_len, 1)
-        last_h, caches = MDL.prefill(params, cfg, batch, max_len, impl=impl)
+        last_h, caches, _ = MDL.prefill(params, cfg, batch, max_len,
+                                        impl=impl)
         logits = MDL.logits_of(params, cfg, last_h[:, None])[:, 0]
         return logits, caches
 
@@ -56,7 +57,9 @@ def make_decode_step(cfg: ModelConfig, *, impl="reference"):
     for the decode_* / long_* shape cells: one new token against a cache."""
 
     def step(params, token, caches, t):
-        return MDL.decode_step(params, cfg, token, caches, t, impl=impl)
+        logits, caches, _ = MDL.decode_step(params, cfg, token, caches, t,
+                                            impl=impl)
+        return logits, caches
 
     return step
 

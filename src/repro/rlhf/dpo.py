@@ -28,7 +28,7 @@ def dpo_loss(hp: DPOHyperparameters, pol_chosen, pol_rejected, ref_chosen,
 
 
 def seq_logp_sum(params, cfg, tokens, mask, gen_start, *, impl="reference"):
-    lp = sequence_logprobs(params, cfg, tokens, gen_start, impl=impl)
+    lp, _ = sequence_logprobs(params, cfg, tokens, gen_start, impl=impl)
     return (lp * mask[:, gen_start:]).sum(-1)
 
 

@@ -154,7 +154,10 @@ class RLHFExperiment:
             actor_cfg, critic_cfg, batch=exp.batch, prompt_len=exp.prompt_len,
             gen_len=exp.gen_len, n_minibatches=exp.ppo.n_minibatches,
             packed=exp.packed_training, draft=exp.draft_model)
-        self.cost = CostModel(cluster)
+        opt = exp.opt
+        self.cost = CostModel(cluster, adam_bytes=(
+            2 * jnp.dtype(opt.state_dtype).itemsize
+            + jnp.dtype(opt.master_dtype).itemsize))
         self.profile_store = None
         if exp.profile_path:
             from repro.core.profiler import ProfileStore, ProfileTable
@@ -250,6 +253,8 @@ class RLHFExperiment:
                 eos_id=exp.eos_id, sampler=exp.sampler, top_k=exp.top_k,
                 top_p=exp.top_p)
 
+        # each call also returns its routing counters (empty without MoE),
+        # which the executors add to the call's record (core/tracing.count)
         def ref_logprobs(p, toks):
             return PPO.sequence_logprobs(p, a_cfg, toks, gen_start,
                                          impl=impl, remat=False)
@@ -260,6 +265,12 @@ class RLHFExperiment:
         def critic_values(p, toks):
             return PPO.sequence_values(p, c_cfg, toks, gen_start, impl=impl,
                                        remat=False)
+
+        def counted(out):
+            """The call's result, its routing counters added to the call's
+            record."""
+            tracing.count("moe", out[1])
+            return out[0]
 
         gen_fn, ref_fn, rew_fn, val_fn = map(
             jax.jit, (actor_generate, ref_logprobs, reward_scores,
@@ -294,6 +305,7 @@ class RLHFExperiment:
             with tracing.span("ppo.step", model=model):
                 ms.params, ms.opt_state, stats = step(ms.params,
                                                       ms.opt_state, batch)
+            tracing.count("moe", stats.pop("moe", {}))
             with tracing.span("ppo.sync", model=model):
                 return jax.tree.map(float, stats)
 
@@ -302,6 +314,7 @@ class RLHFExperiment:
         def actor_gen(ms, inputs):
             state["rng"], k = jax.random.split(state["rng"])
             out = gen_fn(ms.params, inputs["prompts"], k)
+            tracing.count("moe", out.get("moe", {}))
             toks = jnp.concatenate([inputs["prompts"]["tokens"],
                                     out["tokens"]], axis=1)
             mask = out.get("gen_mask", jnp.ones_like(out["logprobs"]))
@@ -355,13 +368,14 @@ class RLHFExperiment:
 
         def reward_inf(ms, inputs):
             full_mask = jnp.ones(inputs["seq"].shape, jnp.float32)
-            return {"rewards": rew_fn(ms.params, inputs["seq"], full_mask)}
+            return {"rewards": counted(rew_fn(ms.params, inputs["seq"],
+                                              full_mask))}
 
         def ref_inf(ms, inputs):
-            return {"ref_logp": ref_fn(ms.params, inputs["seq"])}
+            return {"ref_logp": counted(ref_fn(ms.params, inputs["seq"]))}
 
         def critic_inf(ms, inputs):
-            return {"values": val_fn(ms.params, inputs["seq"])}
+            return {"values": counted(val_fn(ms.params, inputs["seq"]))}
 
         def actor_train(ms, inputs):
             mask = inputs["gen_mask"]

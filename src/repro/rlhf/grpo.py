@@ -41,8 +41,8 @@ def make_grpo_train_step(cfg, hp: GRPOHyperparameters, opt: adamw.AdamWConfig,
         adv = adv_seq[:, None] * batch["mask"]
 
         def loss(p):
-            new_logp = sequence_logprobs(p, cfg, batch["tokens"], gen_start,
-                                         impl=impl)
+            new_logp, _ = sequence_logprobs(p, cfg, batch["tokens"],
+                                            gen_start, impl=impl)
             l, stats = actor_loss_fn(_HP, new_logp, batch["logp"], adv,
                                      batch["mask"])
             # GRPO's explicit KL regularizer (k3 estimator)

@@ -87,22 +87,32 @@ def critic_loss_fn(hp: PPOHyperparameters, new_values, old_values, returns,
 def sequence_logprobs(params, cfg, tokens, gen_start: int, *,
                       impl="reference", remat=True):
     """Log-probs of tokens[t] under the model for the generated region.
-    tokens: (B, S).  Returns (B, S - gen_start)."""
-    h, _ = MDL.forward(params, cfg, {"tokens": tokens}, impl=impl,
-                       remat=remat)
+    tokens: (B, S).  Returns ((B, S - gen_start), the forward's MoE routing
+    counters ({} without MoE, ``model.forward``))."""
+    h, _, counts = MDL.forward(params, cfg, {"tokens": tokens}, impl=impl,
+                               remat=remat)
     logits = MDL.logits_of(params, cfg, h)  # (B, S, V)
     lp = jax.nn.log_softmax(logits[:, gen_start - 1:-1], axis=-1)
     tgt = tokens[:, gen_start:]
-    return jnp.take_along_axis(lp, tgt[..., None], axis=-1)[..., 0]
+    return jnp.take_along_axis(lp, tgt[..., None], axis=-1)[..., 0], counts
 
 
 def sequence_values(params, cfg, tokens, gen_start: int, *, impl="reference",
                     remat=True):
     """Critic values for positions gen_start-1 .. S-1 => (B, T+1) with
-    bootstrap column."""
-    h, _ = MDL.forward(params, cfg, {"tokens": tokens}, impl=impl, remat=remat)
-    v = MDL.values_of(params, h)
-    return v[:, gen_start - 1:]
+    bootstrap column, and the forward's routing counters."""
+    h, _, counts = MDL.forward(params, cfg, {"tokens": tokens}, impl=impl,
+                               remat=remat)
+    return MDL.values_of(params, h)[:, gen_start - 1:], counts
+
+
+def _reduce_stats(stats):
+    """Per-minibatch stats to the step's: means, except the MoE routing
+    counters (``moe``), which are summed and left out where empty."""
+    moe = stats.pop("moe")
+    stats = jax.tree.map(jnp.mean, stats)
+    return {**stats, "moe": jax.tree.map(lambda x: x.sum(0), moe)} \
+        if moe else stats
 
 
 # ------------------------------------------------------------ train steps
@@ -111,17 +121,19 @@ def make_actor_train_step(cfg, hp: PPOHyperparameters, opt: adamw.AdamWConfig,
                           gen_start: int, *, impl="reference"):
     """Returns jit-able f(params, opt_state, batch) -> (params, opt_state,
     stats).  Runs hp.n_minibatches sequential PPO updates (param update per
-    minibatch, matching the paper's workload definition)."""
+    minibatch, matching the paper's workload definition).  MoE models'
+    stats add ``moe``, the forwards' routing counters summed over the
+    minibatches."""
 
     def minibatch_update(carry, mb):
         params, opt_state = carry
 
         def loss(p, mb):
-            new_logp = sequence_logprobs(p, cfg, mb["tokens"], gen_start,
-                                         impl=impl)
+            new_logp, counts = sequence_logprobs(p, cfg, mb["tokens"],
+                                                 gen_start, impl=impl)
             l, stats = actor_loss_fn(hp, new_logp, mb["logp"], mb["adv"],
                                      mb["mask"])
-            return l, stats
+            return l, {**stats, "moe": counts}
 
         (l, stats), grads = jax.value_and_grad(loss, has_aux=True)(params, mb)
         params, opt_state, ostats = adamw.update(opt, params, opt_state, grads)
@@ -133,7 +145,7 @@ def make_actor_train_step(cfg, hp: PPOHyperparameters, opt: adamw.AdamWConfig,
             lambda x: x.reshape(nmb, x.shape[0] // nmb, *x.shape[1:]), batch)
         (params, opt_state), stats = jax.lax.scan(
             minibatch_update, (params, opt_state), mbs)
-        return params, opt_state, jax.tree.map(jnp.mean, stats)
+        return params, opt_state, _reduce_stats(stats)
 
     return step
 
@@ -216,8 +228,8 @@ def packed_sequence_logprobs(params, cfg, batch, *, impl="reference",
     """Target-aligned log-probs over a packed cohort: out[j] =
     log_softmax(logits[j-1])[tokens[j]] (out[0] = 0; the first packed
     token is always a prompt token with mask 0).  Returns (T,)."""
-    h, _ = MDL.forward(params, cfg, batch, impl=impl, remat=remat,
-                       max_seqlen=max_seqlen)
+    h, _, _ = MDL.forward(params, cfg, batch, impl=impl, remat=remat,
+                          max_seqlen=max_seqlen)
     logits = MDL.logits_of(params, cfg, h)[0]  # (T, V)
     lp = jax.nn.log_softmax(logits[:-1], axis=-1)
     tgt = batch["tokens"][1:]
@@ -230,8 +242,8 @@ def packed_sequence_values(params, cfg, batch, *, impl="reference",
     """Critic values per packed position => (T,).  The target-aligned
     prediction for token j is values[j-1] (shift with
     :func:`packed_shift_right`)."""
-    h, _ = MDL.forward(params, cfg, batch, impl=impl, remat=remat,
-                       max_seqlen=max_seqlen)
+    h, _, _ = MDL.forward(params, cfg, batch, impl=impl, remat=remat,
+                          max_seqlen=max_seqlen)
     return MDL.values_of(params, h)[0]
 
 
@@ -307,13 +319,14 @@ def make_critic_train_step(cfg, hp: PPOHyperparameters, opt: adamw.AdamWConfig,
         params, opt_state = carry
 
         def loss(p, mb):
-            v = sequence_values(p, cfg, mb["tokens"], gen_start, impl=impl)
+            v, counts = sequence_values(p, cfg, mb["tokens"], gen_start,
+                                        impl=impl)
             return critic_loss_fn(hp, v[:, :-1], mb["values"], mb["ret"],
-                                  mb["mask"]), {}
+                                  mb["mask"]), {"moe": counts}
 
-        (l, _), grads = jax.value_and_grad(loss, has_aux=True)(params, mb)
+        (l, aux), grads = jax.value_and_grad(loss, has_aux=True)(params, mb)
         params, opt_state, ostats = adamw.update(opt, params, opt_state, grads)
-        return (params, opt_state), {"loss": l, **ostats}
+        return (params, opt_state), {"loss": l, **aux, **ostats}
 
     def step(params, opt_state, batch):
         nmb = hp.n_minibatches
@@ -321,6 +334,6 @@ def make_critic_train_step(cfg, hp: PPOHyperparameters, opt: adamw.AdamWConfig,
             lambda x: x.reshape(nmb, x.shape[0] // nmb, *x.shape[1:]), batch)
         (params, opt_state), stats = jax.lax.scan(
             minibatch_update, (params, opt_state), mbs)
-        return params, opt_state, jax.tree.map(jnp.mean, stats)
+        return params, opt_state, _reduce_stats(stats)
 
     return step

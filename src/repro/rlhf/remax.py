@@ -28,8 +28,8 @@ def make_remax_train_step(cfg, hp: ReMaxHyperparameters,
         adv = (batch["rewards"] - batch["rewards_baseline"])[:, None]
 
         def loss(p):
-            new_logp = sequence_logprobs(p, cfg, batch["tokens"], gen_start,
-                                         impl=impl)
+            new_logp, _ = sequence_logprobs(p, cfg, batch["tokens"],
+                                            gen_start, impl=impl)
             kl = (new_logp - batch["ref_logp"]) * batch["mask"]
             pg = -(adv * new_logp * batch["mask"])
             n = jnp.maximum(batch["mask"].sum(), 1.0)

@@ -26,7 +26,7 @@ def test_forward_shapes_and_finite(arch, reduced):
     cfg = reduced[arch]
     p = init_params(RNG, cfg)
     batch = synth_batch(RNG, cfg, 32, 2, "train")
-    h, aux = forward(p, cfg, batch, remat=False)
+    h, aux, _ = forward(p, cfg, batch, remat=False)
     assert h.shape == (2, 32, cfg.d_model)
     assert bool(jnp.all(jnp.isfinite(h)))
     logits = logits_of(p, cfg, h)
@@ -75,16 +75,16 @@ def test_decode_matches_forward(arch, impl, reduced):
     p = init_params(RNG, cfg)
     S = 24
     batch = synth_batch(RNG, cfg, S, 2, "prefill")
-    h, _ = forward(p, cfg, batch, remat=False)
+    h, _, _ = forward(p, cfg, batch, remat=False)
     full_logits = logits_of(p, cfg, h)
     cut = S - 4
     pb = {k: (v[:, :cut] if k == "tokens" else v) for k, v in batch.items()}
-    last_h, caches = prefill(p, cfg, pb, max_len=S, impl=impl)
+    last_h, caches, _ = prefill(p, cfg, pb, max_len=S, impl=impl)
     lg = logits_of(p, cfg, last_h[:, None])[:, 0]
     errs = [float(jnp.max(jnp.abs(lg - full_logits[:, cut - 1])))]
     for t in range(cut, S - 1):
-        lg, caches = decode_step(p, cfg, batch["tokens"][:, t], caches,
-                                 jnp.int32(t), impl=impl)
+        lg, caches, _ = decode_step(p, cfg, batch["tokens"][:, t], caches,
+                                    jnp.int32(t), impl=impl)
         errs.append(float(jnp.max(jnp.abs(lg - full_logits[:, t]))))
     assert max(errs) < 5e-4, errs
 
@@ -118,7 +118,7 @@ def test_value_head():
     cfg = ARCHS["qwen2-0.5b"].reduced()
     p = init_params(RNG, cfg, head="value")
     batch = synth_batch(RNG, cfg, 8, 2, "prefill")
-    h, _ = forward(p, cfg, batch, remat=False)
+    h, _, _ = forward(p, cfg, batch, remat=False)
     v = values_of(p, h)
     assert v.shape == (2, 8)
     assert bool(jnp.all(jnp.isfinite(v)))
@@ -141,9 +141,9 @@ def test_encdec_uses_encoder():
     cfg = ARCHS["seamless-m4t-medium"].reduced()
     p = init_params(RNG, cfg)
     batch = synth_batch(RNG, cfg, 8, 1, "prefill")
-    h1, _ = forward(p, cfg, batch, remat=False)
+    h1, _, _ = forward(p, cfg, batch, remat=False)
     batch2 = dict(batch, frames=batch["frames"] + 1.0)
-    h2, _ = forward(p, cfg, batch2, remat=False)
+    h2, _, _ = forward(p, cfg, batch2, remat=False)
     assert float(jnp.max(jnp.abs(h1 - h2))) > 1e-4
 
 
@@ -158,9 +158,9 @@ def test_window_attention_ignores_distant_tokens():
         tail=(), num_layers=2)
     p = init_params(RNG, cfg)
     toks = jax.random.randint(RNG, (1, 32), 0, cfg.vocab_size, jnp.int32)
-    h1, _ = forward(p, cfg, {"tokens": toks}, remat=False)
+    h1, _, _ = forward(p, cfg, {"tokens": toks}, remat=False)
     toks2 = toks.at[:, 0].set((toks[:, 0] + 1) % cfg.vocab_size)
-    h2, _ = forward(p, cfg, {"tokens": toks2}, remat=False)
+    h2, _, _ = forward(p, cfg, {"tokens": toks2}, remat=False)
     # position 0 is > 2*window away from the last position with 2 layers
     np.testing.assert_allclose(np.asarray(h1[:, -1]), np.asarray(h2[:, -1]),
                                atol=1e-5)

@@ -56,9 +56,8 @@ def test_grouped_ffn_tiers_match(e, n, d, f, sizes):
     want = ref.grouped_ffn_ref(xs, gs, wg, wi, wo)
     np.testing.assert_allclose(np.asarray(want),
                                _loop_oracle(xs, sizes, wg, wi, wo), atol=1e-4)
-    # the large-shape regime (work-unit scan) computes the same function
-    scanned = ref.grouped_ffn_ref(xs, gs, wg, wi, wo, block_rows=16,
-                                  gather_limit=0)
+    # the large-shape regime (ragged_dot) computes the same function
+    scanned = ref.grouped_ffn_ref(xs, gs, wg, wi, wo, gather_limit=0)
     np.testing.assert_allclose(np.asarray(scanned), np.asarray(want),
                                atol=1e-5)
     # small tiles force boundary-spanning work units and F-tiling
@@ -75,12 +74,64 @@ def test_grouped_ffn_reference_regimes_zero_tail_rows():
     wg, wi, wo = _weights(jax.random.PRNGKey(7), e, d, f)
     gs = jnp.array([5, 0, 6], jnp.int32)  # sums to 11 < 16
     gathered = ref.grouped_ffn_ref(xs, gs, wg, wi, wo)
-    scanned = ref.grouped_ffn_ref(xs, gs, wg, wi, wo, block_rows=8,
-                                  gather_limit=0)
+    scanned = ref.grouped_ffn_ref(xs, gs, wg, wi, wo, gather_limit=0)
     np.testing.assert_allclose(np.asarray(gathered), np.asarray(scanned),
                                atol=1e-5)
     assert np.all(np.asarray(gathered[11:]) == 0.0)
     assert np.abs(np.asarray(gathered[:11])).max() > 0
+
+
+def _undefined_tail_ragged_dot():
+    """``lax.ragged_dot`` as the chip may give it: rows past the last group
+    come out undefined (NaN here), in the product and in its gradient with
+    respect to the rows (the CPU zeroes them)."""
+    real = jax.lax.ragged_dot
+
+    def poison(y, gs):
+        tail = jnp.arange(y.shape[0]) >= jnp.sum(gs)
+        return jnp.where(tail[:, None], jnp.nan, y)
+
+    @jax.custom_vjp
+    def ragged_dot(x, w, gs):
+        return poison(real(x, w, gs), gs)
+
+    def fwd(x, w, gs):
+        return ragged_dot(x, w, gs), (x, w, gs)
+
+    def bwd(res, ct):
+        x, w, gs = res
+        dx, dw = jax.vjp(lambda x, w: real(x, w, gs), x, w)[1](ct)
+        return poison(dx, gs), dw, None
+
+    ragged_dot.defvjp(fwd, bwd)
+    return ragged_dot
+
+
+def test_grouped_ffn_ragged_regime_keeps_undefined_tail_rows_out(monkeypatch):
+    """Rows past the groups (the experts a chip does not hold) stay out of
+    the results and gradients of the rows in groups, even where the grouped
+    matmul leaves them undefined."""
+    e, n, d, f = 3, 40, 16, 24
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    xs = jax.random.normal(ks[0], (n, d), jnp.float32)
+    wg, wi, wo = _weights(ks[1], e, d, f)
+    gs = jnp.array([9, 0, 16], jnp.int32)  # 25 of 40 rows in groups
+    cot = jnp.cos(jnp.arange(n * d, dtype=jnp.float32)).reshape(n, d)
+
+    def value_and_grads():
+        def loss(*a):
+            y = ref.grouped_ffn_ref(a[0], gs, *a[1:], gather_limit=0)
+            return jnp.sum(y * cot), y
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)(
+            xs, wg, wi, wo)
+
+    (_, want), g_want = value_and_grads()
+    monkeypatch.setattr(jax.lax, "ragged_dot", _undefined_tail_ragged_dot())
+    (_, got), g_got = value_and_grads()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for a, b in zip(g_got, g_want):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
 def test_grouped_ffn_ragged_offsets_select_experts():
@@ -154,8 +205,8 @@ def test_dropless_routes_real_tokens_only_when_packed():
     # padded expert rows in the packed dispatch: none, by construction
     assert rows_dispatched(xp) - t_real * cfg.top_k == 0
 
-    y_packed, _ = M.moe_apply(p, cfg, xp)
-    y_padded, _ = M.moe_apply(p, cfg, x)
+    y_packed, _, _ = M.moe_apply(p, cfg, xp)
+    y_padded, _, _ = M.moe_apply(p, cfg, x)
     np.testing.assert_allclose(
         np.asarray(y_packed[0]),
         np.asarray(packing.pack(y_padded, lens)), atol=2e-5)
@@ -176,10 +227,10 @@ def test_dropless_is_cohort_independent(arch):
     p = M.moe_init(RNG, cfg)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 12, cfg.d_model),
                           jnp.float32)
-    full, _ = M.moe_apply(p, cfg, x)
+    full, _, _ = M.moe_apply(p, cfg, x)
     for bi in range(2):
         for si in range(0, 12, 5):
-            one, _ = M.moe_apply(p, cfg, x[bi:bi + 1, si:si + 1])
+            one, _, _ = M.moe_apply(p, cfg, x[bi:bi + 1, si:si + 1])
             np.testing.assert_allclose(np.asarray(one[0, 0]),
                                        np.asarray(full[bi, si]), atol=2e-5)
 
@@ -202,7 +253,7 @@ def test_capacity_is_cohort_dependent_when_overflowing():
     _, _, top_i = M._router(p, cfg, x.reshape(-1, cfg.d_model))
     assert bool(jnp.all(top_i[:, 0] == 0))
     assert 64 > M.capacity(64, cfg)
-    full, _ = M.moe_apply(p, cfg, x)
+    full, _, _ = M.moe_apply(p, cfg, x)
     single = jnp.stack([M.moe_apply(p, cfg, x[b:b + 1, s:s + 1])[0][0, 0]
                         for b in range(4) for s in range(16)])
     assert float(jnp.max(jnp.abs(
@@ -217,9 +268,9 @@ def test_dropless_matches_capacity_when_nothing_drops():
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 4, cfg.d_model),
                           jnp.float32)  # the max(8, ...) capacity floor
     # gives capacity 8 >= any per-expert load at t=4 => nothing drops
-    y_drop, _ = M.moe_apply(p, cfg, x)
-    y_cap, _ = M.moe_apply(p, dataclasses.replace(cfg,
-                                                  moe_dispatch="capacity"), x)
+    y_drop, _, _ = M.moe_apply(p, cfg, x)
+    y_cap, _, _ = M.moe_apply(
+        p, dataclasses.replace(cfg, moe_dispatch="capacity"), x)
     np.testing.assert_allclose(np.asarray(y_drop), np.asarray(y_cap),
                                atol=2e-5)
 
@@ -258,7 +309,7 @@ def test_combine_accumulates_fp32(dispatch):
     p = M.moe_init(RNG, cfg)
     x = jax.random.normal(jax.random.PRNGKey(6), (1, 4, cfg.d_model),
                           jnp.float32)  # small cohort: no capacity drops
-    got, _ = M.moe_apply(p, cfg, x)
+    got, _, _ = M.moe_apply(p, cfg, x)
     xf = x.reshape(-1, cfg.d_model)
     _, top_w, top_i = M._router(p, cfg, xf)
     top_w = np.asarray(top_w / top_w.sum(-1, keepdims=True), np.float64)
@@ -319,7 +370,7 @@ def test_decode_trace_has_no_aux_work(arch):
     cfg = ARCHS[arch].reduced()
     p = init_params(RNG, cfg)
     batch = synth_batch(RNG, cfg, 8, 2, "prefill")
-    _, caches = prefill(p, cfg, batch, max_len=12)
+    _, caches, _ = prefill(p, cfg, batch, max_len=12)
     tok = batch["tokens"][:, -1]
     jx = jax.make_jaxpr(
         lambda tok, caches: decode_step(p, cfg, tok, caches, jnp.int32(8)))(

@@ -133,7 +133,7 @@ def test_ep_sharded_dropless_moe_matches_single_device():
         p = init_params(jax.random.PRNGKey(0), cfg)
         batch = synth_batch(jax.random.PRNGKey(1), cfg, 16, 4, "prefill")
         fwd = lambda p, b: forward(p, cfg, b, remat=False)
-        h1, _ = jax.jit(fwd)(p, batch)
+        h1, *_ = jax.jit(fwd)(p, batch)
 
         mesh = jax.make_mesh((2, 2), ("data", "model"),
                              axis_types=(AxisType.Auto,) * 2)
@@ -147,7 +147,7 @@ def test_ep_sharded_dropless_moe_matches_single_device():
         bsh = jax.tree.map(
             lambda x: NamedSharding(mesh, P("data", *([None]*(x.ndim-1)))),
             batch)
-        h2, _ = jax.jit(fwd, in_shardings=(psh, bsh))(
+        h2, *_ = jax.jit(fwd, in_shardings=(psh, bsh))(
             jax.device_put(p, psh), jax.device_put(batch, bsh))
         np.testing.assert_allclose(np.asarray(h1, np.float32),
                                    np.asarray(h2, np.float32),

@@ -311,7 +311,7 @@ def test_packed_ppo_loss_and_grads_match_padded(gens, tiny_lm, tiny_value):
                "positions": pb.positions}
 
     def actor_padded(p):
-        nl = PPO.sequence_logprobs(p, cfg, toksj, P, remat=False)
+        nl, _ = PPO.sequence_logprobs(p, cfg, toksj, P, remat=False)
         return PPO.actor_loss_fn(HP, nl, jnp.asarray(c["logp"]), adv,
                                  jnp.asarray(c["gen_mask"]))[0]
 
@@ -327,7 +327,7 @@ def test_packed_ppo_loss_and_grads_match_padded(gens, tiny_lm, tiny_value):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
     def critic_padded(p):
-        v = PPO.sequence_values(p, vcfg, toksj, P, remat=False)
+        v, _ = PPO.sequence_values(p, vcfg, toksj, P, remat=False)
         return PPO.critic_loss_fn(HP, v[:, :-1],
                                   jnp.asarray(c["values"][:, :-1]), ret,
                                   jnp.asarray(c["gen_mask"]))

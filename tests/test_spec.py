@@ -191,7 +191,7 @@ def test_spec_logprobs_match_teacher_forced_target():
                              top_k=16)
     toks = np.asarray(out["tokens"])
     full = jnp.concatenate([batch["tokens"], jnp.asarray(toks)], axis=1)
-    hidden, _ = MDL.forward(params, cfg, {"tokens": full}, remat=False)
+    hidden, *_ = MDL.forward(params, cfg, {"tokens": full}, remat=False)
     logits = MDL.logits_of(params, cfg, hidden)
     lps = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     p = batch["tokens"].shape[1]

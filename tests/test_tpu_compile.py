@@ -3,10 +3,11 @@
 Interpret mode does not check the chip's (8, 128) block tiling, SMEM/VMEM
 placement or Mosaic's lowering rules; these tests run the TPU compiler
 against a described (not attached) ``v5e:2x2`` topology at qwen2-0.5b
-widths (14 query heads, 2 KV heads, head_dim 64, bf16).  Nothing runs, so
+widths (14 query heads, 2 KV heads, head_dim 64, bf16), and at
+DeepSeek-V2-Lite's for MLA and the grouped expert GEMM.  Nothing runs, so
 they say nothing about results or speed -- except what the compiled program
 holds: the decode loop of ``generate`` is read for ops that move the KV
-cache.
+cache (the latent cache, for MLA).
 
 The topology is described inside a fixture, never at import, and the
 persistent compilation cache is off around the compiles (an entry written
@@ -90,6 +91,31 @@ def test_paged_flash_decode_compiles(one_chip):
     _compile(lambda q, k, v, t, c: paged_flash_decode(q, k, v, t, cache_len=c),
              one_chip, ((B, HQ, D), BF16), ((n, bs, HKV, D), BF16),
              ((n, bs, HKV, D), BF16), ((B, m), jnp.int32), ((B,), jnp.int32))
+
+
+def test_flash_mha_mla_widths_compile(one_chip):
+    """DeepSeek-V2's expanded MLA prefill: q/k of 192 and v of 128 (zero
+    columns to 192 inside ``ops.mha``) at YaRN's softmax scale, 16 heads."""
+    from repro.kernels import ops
+    s, h = 256, 16
+    _compile(lambda q, k, v: ops.mha(q, k, v, causal=True, scale=0.1147,
+                                     impl="pallas"),
+             one_chip, ((B, s, h, 192), BF16), ((B, s, h, 192), BF16),
+             ((B, s, h, 128), BF16))
+
+
+@pytest.mark.parametrize("rows", [192, 6144])
+def test_grouped_expert_compiles(one_chip, rows):
+    """The grouped expert GEMM at DeepSeek-V2-Lite's widths (D 2048, F 1408)
+    over 8 held experts: a decode step's cohort (32 tokens x top 6) and a
+    dispatch chunk's (1024 tokens x top 6)."""
+    from repro.kernels.grouped_expert import grouped_ffn
+    e, d, f = 8, 2048, 1408
+    compiled = _compile(
+        lambda x, g, wg, wi, wo: grouped_ffn(x, g, wg, wi, wo), one_chip,
+        ((rows, d), BF16), ((e,), jnp.int32), ((e, d, f), BF16),
+        ((e, d, f), BF16), ((e, f, d), BF16))
+    assert "grouped_ffn" in compiled.as_text()
 
 
 def test_flash_mha_varlen_compiles(one_chip):
@@ -204,6 +230,72 @@ def test_decode_loop_moves_no_cache(one_chip, arch, layers, prompt, gen):
             shapes = [a for t in [typ] + operands for a in _arrays(t)]
             touches_cache = any(cap in dims for _, dims in shapes)
             if op in _MOVES and touches_cache and max(
+                    n for n, _ in _arrays(typ)) >= layer_bytes:
+                moves.append(f"{name} {op} {typ[:80]}")
+            if op == "dynamic-update-slice" and touches_cache:
+                update = sum(n for n, _ in _arrays(operands[1]))
+                if update > token_bytes:
+                    updates.append(f"{name}: {update} bytes")
+    assert not moves, moves
+    assert not updates, updates
+
+
+def test_mla_decode_loop_moves_no_latent_cache_in_hbm(one_chip):
+    """``generate(fused=True, impl="pallas")`` of DeepSeek-V2-Lite's share
+    (a dense layer and two MoE layers holding 8 experts, batch 32, prompt
+    128, 512 generated): inside the decode loop no copy, pad or slice
+    writes a buffer of one layer's latent cache or more to HBM, and every
+    update of a cache writes one token.  XLA stages each layer's latents in
+    VMEM for the absorbed attention (a dynamic slice and a relayout there,
+    memory space ``S(1)``): that reads the layer once from HBM and writes
+    nothing back; a latent-cache decode kernel would read it in place."""
+    from repro.configs import ARCHS
+    from repro.models import model as MDL
+    cfg = dataclasses.replace(ARCHS["deepseek-v2-lite"], num_layers=3,
+                              n_superblocks=2, vocab_size=4096,
+                              n_local_experts=8)
+    b, prompt, gen = 32, 128, 512
+    cap, width = prompt + gen, cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    layer_bytes, token_bytes = b * cap * width * 2, b * width * 2
+    on_chip = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: MDL.init_params(k, cfg), jax.random.PRNGKey(0)))
+    batch = {"tokens": on_chip(jax.ShapeDtypeStruct((b, prompt), jnp.int32))}
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    hlo = jax.jit(lambda p, bt, k: MDL.generate(
+        p, cfg, bt, num_new_tokens=gen, rng=k, impl="pallas", fused=True)
+    ).lower(params, batch, key).compile().as_text()
+
+    comps = _computations(hlo)
+
+    def reach(c, seen):
+        if c not in seen and c in comps:
+            seen.add(c)
+            for _, _, rest in comps[c].values():
+                for cc in _called(rest):
+                    reach(cc, seen)
+        return seen
+    # the decode loop carries the caches and the (gen - 1, B) sampled tokens
+    loops = [reach(_called(rest, "body")[0], set())
+             for ins in comps.values() for typ, op, rest in ins.values()
+             if op == "while" and f"{cap},{width}]" in typ
+             and f"[{gen - 1},{b}]" in typ]
+    assert len(loops) == 1, "no single decode loop"
+    bodies = loops[0]
+    assert any(n.startswith("grouped_ffn") for c in bodies for n in comps[c])
+    moves, updates = [], []
+    for c in bodies:
+        ins = comps[c]
+        for name, (typ, op, rest) in ins.items():
+            operands = [ins[o][0] for o in re.findall(r"%([\w.\-]+)",
+                                                       rest.split("),")[0])
+                        if o in ins]
+            shapes = [a for t in [typ] + operands for a in _arrays(t)]
+            touches_cache = any(cap in dims and width in dims
+                                for _, dims in shapes)
+            in_hbm = "S(1)" not in typ
+            if op in _MOVES and touches_cache and in_hbm and max(
                     n for n, _ in _arrays(typ)) >= layer_bytes:
                 moves.append(f"{name} {op} {typ[:80]}")
             if op == "dynamic-update-slice" and touches_cache:

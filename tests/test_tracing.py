@@ -167,3 +167,26 @@ def test_each_thread_counts_into_its_own_record():
         sys.setswitchinterval(old)
     assert [r.lowerings for r in recs] == [per_thread] * n_threads
     assert after.lowerings - before.lowerings == per_thread
+
+
+def test_counters_add_into_the_current_record_only():
+    """``tracing.count`` adds trees into the record its thread made current
+    with ``counting``, one tree per name, and does nothing outside one."""
+    tracing.count("moe", {"held": jnp.ones(2, jnp.int32)})  # no record
+    rec = {}
+    with tracing.counting(rec):
+        tracing.count("moe", {"held": jnp.array([1, 2])})
+        tracing.count("moe", {"held": jnp.array([3, 4])})
+        tracing.count("other", jnp.int32(5))
+        tracing.count("other", jnp.int32(0))
+        tracing.count("empty", {})  # a model without MoE counts nothing
+        inner = {}
+        with tracing.counting(inner):
+            tracing.count("moe", {"held": jnp.array([9, 9])})
+        tracing.count("moe", {"held": jnp.array([0, 1])})
+    assert rec["moe"]["held"].tolist() == [4, 7]
+    assert int(rec["other"]) == 5 and "empty" not in rec
+    assert inner["moe"]["held"].tolist() == [9, 9]
+    total = {"moe": {"held": jnp.array([1, 1])}}
+    tracing.add_counts(total, rec)
+    assert total["moe"]["held"].tolist() == [5, 8] and "other" in total
