@@ -272,9 +272,15 @@ class RLHFExperiment:
             tracing.count("moe", out[1])
             return out[0]
 
-        gen_fn, ref_fn, rew_fn, val_fn = map(
+        # reward shaping + GAE of the padded train calls: one program,
+        # compiled once per shape (hp is closed over, not traced)
+        def advantages(final_reward, logp, ref_logp, values, mask):
+            return PPO.advantages(hp, final_reward, logp, ref_logp, values,
+                                  mask)
+
+        gen_fn, ref_fn, rew_fn, val_fn, adv_fn = map(
             jax.jit, (actor_generate, ref_logprobs, reward_scores,
-                      critic_values))
+                      critic_values, advantages))
         if exp.packed_training:
             # one static max_seqlen (the padded S) keys the banded varlen
             # reference; per-iteration token totals vary but are bucketed
@@ -377,26 +383,22 @@ class RLHFExperiment:
         def critic_inf(ms, inputs):
             return {"values": counted(val_fn(ms.params, inputs["seq"]))}
 
+        def estimate(model, inputs):
+            with tracing.span("ppo.adv", model=model):
+                return adv_fn(inputs["rewards"], inputs["logp"],
+                              inputs["ref_logp"], inputs["values"],
+                              inputs["gen_mask"])
+
         def actor_train(ms, inputs):
-            mask = inputs["gen_mask"]
-            with tracing.span("ppo.adv", model="actor"):
-                shaped = PPO.shaped_rewards(hp, inputs["rewards"],
-                                            inputs["logp"],
-                                            inputs["ref_logp"], mask)
-                adv, _ = PPO.gae(hp, shaped, inputs["values"], mask)
+            adv, _ = estimate("actor", inputs)
             batch = {"tokens": inputs["seq"], "logp": inputs["logp"],
-                     "adv": adv, "mask": mask}
+                     "adv": adv, "mask": inputs["gen_mask"]}
             return {"actor_stats": train("actor", actor_step, ms, batch)}
 
         def critic_train(ms, inputs):
-            mask = inputs["gen_mask"]
-            with tracing.span("ppo.adv", model="critic"):
-                shaped = PPO.shaped_rewards(hp, inputs["rewards"],
-                                            inputs["logp"],
-                                            inputs["ref_logp"], mask)
-                _, ret = PPO.gae(hp, shaped, inputs["values"], mask)
+            _, ret = estimate("critic", inputs)
             batch = {"tokens": inputs["seq"], "values": inputs["values"][:, :-1],
-                     "ret": ret, "mask": mask}
+                     "ret": ret, "mask": inputs["gen_mask"]}
             return {"critic_stats": train("critic", critic_step, ms, batch)}
 
         # ---------------------------------------------- packed train path

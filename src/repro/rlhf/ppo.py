@@ -61,6 +61,15 @@ def gae(hp: PPOHyperparameters, rewards, values, mask):
     return adv, ret
 
 
+def advantages(hp: PPOHyperparameters, final_reward, logp, ref_logp, values,
+               mask):
+    """The padded path's whole advantage estimate, :func:`shaped_rewards`
+    then :func:`gae`, as one function to ``jit`` with ``hp`` closed over.
+    Returns (adv, ret)."""
+    return gae(hp, shaped_rewards(hp, final_reward, logp, ref_logp, mask),
+               values, mask)
+
+
 def actor_loss_fn(hp: PPOHyperparameters, new_logp, old_logp, adv, mask):
     ratio = jnp.exp(jnp.clip(new_logp - old_logp, -20.0, 20.0))
     unclipped = ratio * adv

@@ -1,9 +1,12 @@
 """RLHF algorithm math: GAE vs. a naive python reference, PPO clipping,
 DPO/GRPO properties."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.rlhf import dpo as DPO
@@ -26,6 +29,14 @@ def naive_gae(hp, rewards, values, mask):
     return adv * mask
 
 
+def whiten(raw, mask):
+    """The advantage whitening over valid tokens that ``PPO.gae`` applies."""
+    n = max(mask.sum(), 1.0)
+    mean = (raw * mask).sum() / n
+    var = (((raw - mean) ** 2) * mask).sum() / n
+    return (raw - mean) / np.sqrt(var + 1e-8) * mask
+
+
 @settings(max_examples=15, deadline=None)
 @given(st.integers(1, 4), st.integers(2, 12), st.integers(0, 10**6))
 def test_gae_matches_naive(b, t, seed):
@@ -37,14 +48,37 @@ def test_gae_matches_naive(b, t, seed):
     adv, ret = PPO.gae(HP, jnp.asarray(rewards), jnp.asarray(values),
                        jnp.asarray(mask))
     raw = naive_gae(HP, rewards, values, mask)
-    # un-whiten the jax result to compare against the raw reference
-    n = max(mask.sum(), 1.0)
-    mean = (raw * mask).sum() / n
-    var = (((raw - mean) ** 2) * mask).sum() / n
-    white = (raw - mean) / np.sqrt(var + 1e-8) * mask
-    np.testing.assert_allclose(np.asarray(adv), white, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(adv), whiten(raw, mask), atol=2e-3)
     np.testing.assert_allclose(np.asarray(ret), raw + values[:, :-1] * mask,
                                atol=2e-3)
+
+
+@pytest.mark.parametrize("b,t,lens", [
+    (1, 2, [2]),                  # one sequence, no EOS
+    (3, 9, [9, 4, 1]),            # EOS early, one after the first token
+    (4, 16, [16, 16, 16, 16]),    # every mask full
+    (8, 64, [64, 7, 33, 2, 64, 50, 1, 63]),
+])
+def test_jitted_advantages_match_eager_and_naive(b, t, lens):
+    rng = np.random.default_rng(b * 1000 + t)
+    final = rng.normal(size=(b,)).astype(np.float32)
+    logp = -rng.exponential(size=(b, t)).astype(np.float32)
+    ref_logp = -rng.exponential(size=(b, t)).astype(np.float32)
+    values = rng.normal(size=(b, t + 1)).astype(np.float32)
+    mask = (np.arange(t)[None] < np.asarray(lens)[:, None]).astype(
+        np.float32)
+    args = tuple(map(jnp.asarray, (final, logp, ref_logp, values, mask)))
+    adv, ret = jax.jit(functools.partial(PPO.advantages, HP))(*args)
+    shaped = PPO.shaped_rewards(HP, *args[:3], args[4])
+    e_adv, e_ret = PPO.gae(HP, shaped, args[3], args[4])
+    np.testing.assert_allclose(np.asarray(adv), np.asarray(e_adv),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(ret), np.asarray(e_ret),
+                               rtol=1e-5, atol=1e-6)
+    raw = naive_gae(HP, np.asarray(shaped, np.float64), values, mask)
+    np.testing.assert_allclose(np.asarray(adv), whiten(raw, mask), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(ret), raw + values[:, :-1] * mask,
+                               atol=1e-4)
 
 
 def test_shaped_rewards_places_final_reward_at_last_token():
